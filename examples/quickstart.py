@@ -13,6 +13,7 @@ import numpy as np
 
 from repro.core import adaptive, pim_linear as plin
 from repro.core import energy as en, workloads as wl
+from repro.kernels import ops as kops
 
 
 def main() -> None:
@@ -39,9 +40,10 @@ def main() -> None:
           f"speculation failure rate {float(st.failure_rate):.1%}")
 
     print("\n=== TPU-native fast path (Eq. 1 as centered int8 matmul) ===")
-    y_fast = plin.forward_fast(x, plan, use_pallas=True)
+    y_fast = plin.forward_fast(x, plan)
     rel = float(jnp.linalg.norm(y_fast - y_ref) / jnp.linalg.norm(y_ref))
-    print(f"rel error vs float: {rel:.4f} (Pallas kernel, interpret mode)")
+    backend = kops.resolve_backend("centered_int8_matmul")
+    print(f"rel error vs float: {rel:.4f} (kernel backend {backend})")
 
     print("\n=== Titanium Law: ResNet18 on RAELLA vs 8b ISAAC ===")
     layers = wl.resnet18()

@@ -51,7 +51,6 @@ class ArchConfig:
     # Consumed by repro.models (pim_matmul) and both serve engines; plans
     # come from repro.models.pim.prepare_pim_params.
     pim_mode: str = "off"
-    pim_use_pallas: bool = False       # fast path: Pallas kernel vs XLA ref
     # Kernel backend for the repro.kernels.ops registry (fused exact
     # datapath + fast-path matmul): "auto" picks pallas-tpu on TPU and
     # the XLA reference elsewhere; "interpret" forces the Pallas
@@ -97,8 +96,7 @@ class ArchConfig:
             raise ValueError(
                 f"{self.name}: pim_weight_slicing {ws!r} must cover 8 weight "
                 "bits with 1..4b slices (paper: <=4b ReRAM devices)")
-        allowed = ("auto", "xla", "interpret", "pallas", "pallas-tpu",
-                   "pallas-gpu", "python")
+        allowed = ("auto", "xla", "interpret", "pallas-tpu", "python")
         if self.pim_kernel_backend not in allowed:
             raise ValueError(
                 f"{self.name}: pim_kernel_backend "

@@ -175,7 +175,7 @@ def forward_int_reference(x: jnp.ndarray, plan: PimPlan) -> jnp.ndarray:
     return q.dequantize(y_int, plan.lq, x_q.sum(-1), w_col_sum)
 
 
-def forward_fast(x: jnp.ndarray, plan: PimPlan, *, use_pallas: bool = False,
+def forward_fast(x: jnp.ndarray, plan: PimPlan, *,
                  backend: str | None = None) -> jnp.ndarray:
     """TPU-native centered-int8 path (no ADC model — deployment arithmetic).
 
@@ -184,8 +184,8 @@ def forward_fast(x: jnp.ndarray, plan: PimPlan, *, use_pallas: bool = False,
     where (W_off, phi, s_w) come from asymmetric per-channel centered
     quantization — offsets guaranteed int8, centers digital.
 
-    ``backend`` (or ``plan.kernel_backend``) selects a registry backend
-    by name; otherwise the legacy ``use_pallas`` flag applies.
+    ``backend`` (or ``plan.kernel_backend``) names a registry backend;
+    ``'python'`` (an exact-path choice) resolves like ``'auto'`` here.
     """
     from repro.kernels import ops as kops
     if plan.lq.x_signed:
@@ -196,14 +196,9 @@ def forward_fast(x: jnp.ndarray, plan: PimPlan, *, use_pallas: bool = False,
         x_q = (jnp.clip(jnp.round(x / plan.lq.x_scale), 0, 255) - 128).astype(jnp.int8)
         shift = 128
     be = backend or plan.kernel_backend
-    if be is not None and be not in ("auto", "python"):
-        y_int = kops.centered_int8_matmul(
-            x_q, jnp.asarray(plan.fast_w_off), jnp.asarray(plan.fast_centers),
-            backend=be)
-    else:
-        y_int = kops.centered_int8_matmul(
-            x_q, jnp.asarray(plan.fast_w_off), jnp.asarray(plan.fast_centers),
-            use_pallas=use_pallas)
+    y_int = kops.centered_int8_matmul(
+        x_q, jnp.asarray(plan.fast_w_off), jnp.asarray(plan.fast_centers),
+        backend=None if be == "python" else be)
     if shift:
         # undo the input shift: u @ W = (u-128) @ W + 128 * colsum(W_off + phi)
         w_col = (plan.fast_w_off.astype(np.int64).sum(axis=0)

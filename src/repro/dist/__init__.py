@@ -1,10 +1,6 @@
-"""Distribution layer: logical-axis sharding rules + jax compat shims."""
+"""Distribution layer: logical-axis sharding rules."""
 
-from repro.dist import compat as _compat
-
-_compat.install()  # before anything reads jax.sharding.*
-
-from repro.dist.sharding import (  # noqa: E402
+from repro.dist.sharding import (
     DEFAULT_RULES,
     MULTIPOD_RULES,
     MULTIPOD_SERVE_RULES,

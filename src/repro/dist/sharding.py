@@ -222,27 +222,15 @@ def sanitize_shardings(shardings, abstract_tree):
             from e
 
 
-_warned_no_mesh_api = False
-
-
 def _active_mesh():
-    """The physical mesh bound by ``with mesh:``, or None."""
-    global _warned_no_mesh_api
-    try:
-        from jax._src import mesh as mesh_lib
-        m = mesh_lib.thread_resources.env.physical_mesh
-        if m is not None and not m.empty:
-            return m
-    except (ImportError, AttributeError):
-        # private API moved: warn once instead of silently disabling every
-        # sharding constraint (which would only show up as lost throughput)
-        if not _warned_no_mesh_api:
-            _warned_no_mesh_api = True
-            import warnings
-            warnings.warn(
-                "repro.dist: cannot read the active mesh from this jax "
-                "version (jax._src.mesh.thread_resources missing); shard() "
-                "constraints are DISABLED", RuntimeWarning, stacklevel=2)
+    """The physical mesh bound by ``with mesh:``, or None.
+
+    Reads jax's private ``thread_resources``; if that API moves, this
+    raises rather than switching every sharding constraint off."""
+    from jax._src import mesh as mesh_lib
+    m = mesh_lib.thread_resources.env.physical_mesh
+    if m is not None and not m.empty:
+        return m
     return None
 
 

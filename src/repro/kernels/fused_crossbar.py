@@ -45,6 +45,10 @@ from jax.experimental.pallas import tpu as pltpu
 ROWS_PER_XBAR = 512
 DEFAULT_BM = 128
 DEFAULT_BN = 256
+# whole-array SMEM operand: the per-slice scalar tables and the counters,
+# indexed by grid position (a TPU block's last two dims must tile (8, 128)
+# or span the array, which a (1, 1) VMEM block of a table does not)
+_SMEM = pl.BlockSpec(memory_space=pltpu.SMEM)
 
 
 def _kernel(x_ref, w_ref, li_ref, mask_ref, mult_ref, cen_ref,
@@ -64,7 +68,7 @@ def _kernel(x_ref, w_ref, li_ref, mask_ref, mult_ref, cen_ref,
 
     @pl.when(first & (b == 0) & (c == 0))
     def _init_sat():
-        sat_ref[0, 0] = jnp.zeros((), jnp.int32)
+        sat_ref[0, 0] = 0
 
     x = x_ref[...]  # (bm, rows_per_xbar) int32, unsigned 8b codes
 
@@ -74,7 +78,7 @@ def _kernel(x_ref, w_ref, li_ref, mask_ref, mult_ref, cen_ref,
         acc_ref[...] += x.sum(axis=1, keepdims=True) * cen_ref[0]
 
     # temporal input slicing, in-register: (x >> l_i) & ((1 << w_i) - 1)
-    x_i = jax.lax.shift_right_logical(x, li_ref[0, 0]) & mask_ref[0, 0]
+    x_i = jax.lax.shift_right_logical(x, li_ref[0, i]) & mask_ref[0, i]
     if narrow:  # every slice value < 128 -> int8 x int8 MXU dot
         cs = jax.lax.dot(x_i.astype(jnp.int8), w_ref[0],
                          preferred_element_type=jnp.int32)
@@ -90,7 +94,7 @@ def _kernel(x_ref, w_ref, li_ref, mask_ref, mult_ref, cen_ref,
     sat = ((cs == adc_lo) | (cs == adc_hi)) & in_bounds
     sat_ref[0, 0] += sat.astype(jnp.int32).sum()
 
-    acc_ref[...] += cs * mult_ref[0, 0]  # digital shift+add
+    acc_ref[...] += cs * mult_ref[i, j]  # digital shift+add
 
     last = (s == n_seg - 1) & (i == n_i - 1) & (j == n_j - 1)
 
@@ -145,14 +149,12 @@ def fused_crossbar(x_u8: jnp.ndarray, w_planes: jnp.ndarray,
         in_specs=[
             pl.BlockSpec((bm, rows_per_xbar), lambda b, c, s, i, j: (b, s)),
             pl.BlockSpec((1, rows_per_xbar, bn), lambda b, c, s, i, j: (j, s, c)),
-            pl.BlockSpec((1, 1), lambda b, c, s, i, j: (i, 0)),
-            pl.BlockSpec((1, 1), lambda b, c, s, i, j: (i, 0)),
-            pl.BlockSpec((1, 1), lambda b, c, s, i, j: (i, j)),
-            pl.BlockSpec((1, bn), lambda b, c, s, i, j: (s, c)),
+            _SMEM, _SMEM, _SMEM,
+            pl.BlockSpec((1, 1, bn), lambda b, c, s, i, j: (s, 0, c)),
         ],
         out_specs=[
             pl.BlockSpec((bm, bn), lambda b, c, s, i, j: (b, c)),
-            pl.BlockSpec((1, 1), lambda b, c, s, i, j: (0, 0)),
+            _SMEM,
         ],
         out_shape=[
             jax.ShapeDtypeStruct((Bp, Cp), jnp.int32),
@@ -161,9 +163,9 @@ def fused_crossbar(x_u8: jnp.ndarray, w_planes: jnp.ndarray,
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
         interpret=interpret,
     )(x_p, w_p,
-      in_li.astype(jnp.int32).reshape(n_i, 1),
-      in_mask.astype(jnp.int32).reshape(n_i, 1),
-      mults.astype(jnp.int32), cen_p)
+      in_li.astype(jnp.int32).reshape(1, n_i),
+      in_mask.astype(jnp.int32).reshape(1, n_i),
+      mults.astype(jnp.int32), cen_p[:, None, :])
     return psum[:B, :C], sats[0, 0]
 
 

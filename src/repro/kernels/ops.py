@@ -9,8 +9,6 @@ Every op is registered under one or more *backends*:
                 semantics on any backend, slow; what CI forces to catch
                 kernel regressions without TPU runners.
   pallas-tpu  — the Pallas kernel lowered natively (requires a TPU).
-  pallas-gpu  — reserved; no Triton ports exist yet, so requests fall
-                back down the chain below.
 
 Selection order, first match wins:
 
@@ -19,10 +17,9 @@ Selection order, first match wins:
      ``ArchConfig.pim_kernel_backend`` by the model dispatch path);
   3. ``auto``: ``pallas-tpu`` on TPU, else ``xla``.
 
-Two aliases resolve before lookup: ``auto`` (above) and ``pallas``
-(``pallas-tpu`` on TPU, else ``interpret`` — the legacy ``use_pallas``
-semantics). A backend not registered for an op falls back to ``xla``,
-which exists for every op, so resolution never fails on a valid name.
+``auto`` is the only alias. Every other name must be registered for the
+op, or resolution raises: a request never falls silently to another
+backend, so a run that asked for the chip's kernels gets them or fails.
 """
 
 from __future__ import annotations
@@ -34,6 +31,7 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 
+from repro.dist.sharding import _active_mesh
 from repro.kernels import fused_crossbar as _fx
 from repro.kernels import fused_spec_crossbar as _fs
 from repro.kernels import int8_matmul as _im
@@ -41,8 +39,7 @@ from repro.kernels import ref as _ref
 from repro.kernels import sliced_crossbar as _sx
 
 ENV_VAR = "REPRO_KERNEL_BACKEND"
-BACKENDS = ("xla", "interpret", "pallas-tpu", "pallas-gpu")
-ALIASES = ("auto", "pallas")
+BACKENDS = ("xla", "interpret", "pallas-tpu")
 
 _REGISTRY: dict[str, dict[str, Callable]] = {}
 
@@ -55,8 +52,7 @@ def register(op: str, backend: str, fn: Callable) -> None:
 
 
 def backends(op: str) -> tuple[str, ...]:
-    """Backends registered for ``op`` (resolution may still pick others
-    via the xla fallback)."""
+    """Backends registered for ``op``."""
     return tuple(sorted(_REGISTRY[op]))
 
 
@@ -67,23 +63,19 @@ def _on_tpu() -> bool:
 def resolve_backend(op: str, request: str | None = None) -> str:
     """Resolve a backend request to a registered backend name for ``op``.
 
-    ``request=None`` means ``auto``. Order: env override, request, auto;
-    aliases expand per the module docstring; unregistered backends fall
-    back to ``xla``.
+    ``request=None`` means ``auto``. Order: env override, request, auto.
+    ``auto`` is ``pallas-tpu`` on TPU and ``xla`` elsewhere; any other
+    name that is not registered for ``op`` raises ``ValueError``.
     """
     if op not in _REGISTRY:
         raise KeyError(f"unknown kernel op {op!r}; have {sorted(_REGISTRY)}")
     name = os.environ.get(ENV_VAR) or request or "auto"
-    if name not in BACKENDS + ALIASES:
-        raise ValueError(
-            f"unknown kernel backend {name!r}; expected one of "
-            f"{BACKENDS + ALIASES}")
     if name == "auto":
         name = "pallas-tpu" if _on_tpu() else "xla"
-    elif name == "pallas":
-        name = "pallas-tpu" if _on_tpu() else "interpret"
     if name not in _REGISTRY[op]:
-        name = "xla"
+        raise ValueError(
+            f"kernel backend {name!r} is not registered for {op!r}; "
+            f"have {backends(op)} (or 'auto')")
     return name
 
 
@@ -94,16 +86,10 @@ def dispatch(op: str, request: str | None = None) -> Callable:
 # ------------------------------------------------------------------ ops
 def centered_int8_matmul(x_q: jnp.ndarray, w_off: jnp.ndarray,
                          centers: jnp.ndarray, *,
-                         use_pallas: bool = False,
                          backend: str | None = None) -> jnp.ndarray:
     """y_int32 = x_q @ w_off + rowsum(x_q) * centers (Eq. 1 fast path).
 
-    ``backend`` follows the registry selection order; the legacy
-    ``use_pallas`` flag (= backend 'pallas' / 'xla') applies only when
-    ``backend`` is not given.
-    """
-    if backend is None and use_pallas:
-        backend = "pallas"
+    ``backend`` follows the registry selection order."""
     return dispatch("centered_int8_matmul", backend)(x_q, w_off, centers)
 
 
@@ -111,11 +97,8 @@ def sliced_crossbar_matmul(x_slices: jnp.ndarray, w_planes: jnp.ndarray,
                            mults: jnp.ndarray, *,
                            adc_lo: int = -64, adc_hi: int = 63,
                            rows_per_xbar: int = 512,
-                           use_pallas: bool = False,
                            backend: str | None = None) -> jnp.ndarray:
     """RAELLA crossbar contraction with per-segment ADC clamp."""
-    if backend is None and use_pallas:
-        backend = "pallas"
     return dispatch("sliced_crossbar_matmul", backend)(
         x_slices, w_planes, mults, adc_lo=adc_lo, adc_hi=adc_hi,
         rows_per_xbar=rows_per_xbar)
@@ -247,6 +230,27 @@ def fused_spec_crossbar_forward(x_u8: jnp.ndarray, planes: jnp.ndarray,
 
 
 # ------------------------------------------------------------- registry
+def _whole_on_each_device(fn):
+    """GSPMD cannot partition a Mosaic kernel, so under a multi-device
+    mesh (``with mesh:``) the kernel runs whole on every device of it,
+    its operands replicated. Outside a mesh, or on one device, the
+    kernel is called as it is."""
+    @functools.wraps(fn)
+    def wrapped(*args, **kwargs):
+        mesh = _active_mesh()
+        if mesh is None or mesh.size == 1:
+            return fn(*args, **kwargs)
+        P = jax.sharding.PartitionSpec
+        return jax.shard_map(functools.partial(fn, **kwargs), mesh=mesh,
+                             in_specs=(P(),) * len(args), out_specs=P(),
+                             check_vma=False)(*args)
+    return wrapped
+
+
+def _pallas(fn, **kwargs):
+    return _whole_on_each_device(functools.partial(fn, **kwargs))
+
+
 def _drop_narrow(fn):
     """The XLA reference needs no narrow/int8 hint — accept and drop it."""
     @functools.wraps(fn)
@@ -258,24 +262,24 @@ def _drop_narrow(fn):
 
 register("centered_int8_matmul", "xla", _ref.centered_int8_matmul)
 register("centered_int8_matmul", "interpret",
-         functools.partial(_im.centered_int8_matmul, interpret=True))
+         _pallas(_im.centered_int8_matmul, interpret=True))
 register("centered_int8_matmul", "pallas-tpu",
-         functools.partial(_im.centered_int8_matmul, interpret=False))
+         _pallas(_im.centered_int8_matmul, interpret=False))
 
 register("sliced_crossbar_matmul", "xla", _ref.sliced_crossbar_matmul)
 register("sliced_crossbar_matmul", "interpret",
-         functools.partial(_sx.sliced_crossbar_matmul, interpret=True))
+         _pallas(_sx.sliced_crossbar_matmul, interpret=True))
 register("sliced_crossbar_matmul", "pallas-tpu",
-         functools.partial(_sx.sliced_crossbar_matmul, interpret=False))
+         _pallas(_sx.sliced_crossbar_matmul, interpret=False))
 
 register("fused_crossbar", "xla", _drop_narrow(_ref.fused_crossbar))
 register("fused_crossbar", "interpret",
-         functools.partial(_fx.fused_crossbar, interpret=True))
+         _pallas(_fx.fused_crossbar, interpret=True))
 register("fused_crossbar", "pallas-tpu",
-         functools.partial(_fx.fused_crossbar, interpret=False))
+         _pallas(_fx.fused_crossbar, interpret=False))
 
 register("fused_spec_crossbar", "xla", _drop_narrow(_ref.fused_spec_crossbar))
 register("fused_spec_crossbar", "interpret",
-         functools.partial(_fs.fused_spec_crossbar, interpret=True))
+         _pallas(_fs.fused_spec_crossbar, interpret=True))
 register("fused_spec_crossbar", "pallas-tpu",
-         functools.partial(_fs.fused_spec_crossbar, interpret=False))
+         _pallas(_fs.fused_spec_crossbar, interpret=False))

@@ -8,9 +8,6 @@ from __future__ import annotations
 
 import jax
 
-import repro.dist  # noqa: F401  (installs jax API compat shims: AxisType,
-#                                 make_mesh(axis_types=...) on jax < 0.5)
-
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
     """16x16 = 256 chips per pod; 2 pods = 512 chips multi-pod."""

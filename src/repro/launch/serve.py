@@ -23,18 +23,28 @@ site (printing the slice-count histogram and per-site table);
 stuck-at faults, and IR drop at the named corner. See
 ``benchmarks/compile_report.py`` for the Titanium-Law pricing of the
 compiled plan.
+
+The first line printed names the platform, device kind and device count,
+and the backend each kernel op resolved to. ``main`` runs
+``parse_args`` -> ``setup`` -> ``make_engine`` -> ``run``;
+``chip_smoke.py`` drives the same steps on the chip.
 """
 
 from __future__ import annotations
 
 import argparse
 import dataclasses
+import os
+import pathlib
 import time
+from typing import Any
 
 import jax
 import numpy as np
 
 from repro import configs, obs
+from repro.configs.base import ArchConfig
+from repro.kernels import ops as kops
 from repro.models import pim
 from repro.models import transformer as T
 from repro.serve import (
@@ -61,7 +71,39 @@ def build_trace(n: int, *, prompt_len: int, steps: int, vocab: int,
     return reqs
 
 
-def main() -> None:
+KERNEL_OPS = ("centered_int8_matmul", "fused_crossbar",
+              "fused_spec_crossbar")
+
+
+def use_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache at a fixed place.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and
+    nothing is set here. Otherwise the cache lives in ``.jax_cache`` at
+    the root of the checkout (git-ignored): a fixed path, because a
+    cache whose path moves is never hit. Returns the directory in use.
+    """
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    path = str(pathlib.Path(__file__).resolve().parents[3] / ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
+
+
+def device_report(kernel_backend: str | None = None) -> dict:
+    """The devices this process runs on and the backend each kernel op
+    resolves to for ``kernel_backend`` (``'python'`` — an exact-path
+    choice of the reference loop — resolves like ``'auto'``)."""
+    devs = jax.devices()
+    request = None if kernel_backend == "python" else kernel_backend
+    return {"platform": devs[0].platform, "kind": devs[0].device_kind,
+            "count": len(devs),
+            "kernels": {op: kops.resolve_backend(op, request)
+                        for op in KERNEL_OPS}}
+
+
+def parse_args(argv: list[str] | None = None) -> argparse.Namespace:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--reduced", action="store_true")
@@ -107,47 +149,59 @@ def main() -> None:
     ap.add_argument("--device-seed", type=int, default=0,
                     help="die seed for --device-corner fault/noise maps")
     ap.add_argument("--kernel-backend", default=None,
-                    choices=("auto", "xla", "interpret", "pallas",
-                             "pallas-tpu", "pallas-gpu", "python"),
+                    choices=("auto", "xla", "interpret", "pallas-tpu",
+                             "python"),
                     help="repro.kernels.ops registry backend for the PIM "
                          "kernels (fused exact datapath / fast matmul); "
                          "'auto' = pallas-tpu on TPU, XLA ref elsewhere. "
                          "REPRO_KERNEL_BACKEND overrides at dispatch time")
-    args = ap.parse_args()
-
+    args = ap.parse_args(argv)
     if args.engine == "lockstep" and (args.metrics_out or args.trace_out
                                       or args.profile_dir):
         ap.error("--metrics-out/--trace-out/--profile-dir instrument the "
                  "continuous/paged scheduler loops; the lockstep "
                  "reference engine has no request lifecycle to trace")
+    if args.kernel_backend is not None and args.pim == "off":
+        ap.error("--kernel-backend requires --pim fast|exact|int8")
+    if args.device_corner is not None and args.pim != "exact":
+        ap.error("--device-corner requires --pim exact (only the "
+                 "bit-exact accelerator simulation models the analog "
+                 "array)")
+    if args.pim_slicing is not None and args.pim == "off":
+        ap.error("--pim-slicing requires --pim fast|exact|int8 "
+                 "(the float path has no compile step)")
+    return args
+
+
+@dataclasses.dataclass
+class Setup:
+    """What one serve run is built from: config, weights, PIM plans."""
+    cfg: ArchConfig
+    params: Any
+    plans: Any = None
+
+
+def setup(args: argparse.Namespace) -> Setup:
+    """Config from ``args``, random weights from a fixed seed, and the
+    compiled PIM plans (on a seeded random calibration batch)."""
     cfg = configs.get(args.arch)
     if args.reduced:
         cfg = cfg.reduced()
     if args.pim != cfg.pim_mode:
         cfg = dataclasses.replace(cfg, pim_mode=args.pim)
     if args.kernel_backend is not None:
-        if cfg.pim_mode == "off":
-            ap.error("--kernel-backend requires --pim fast|exact|int8")
         cfg = dataclasses.replace(cfg, pim_kernel_backend=args.kernel_backend)
     if args.device_corner is not None:
-        if cfg.pim_mode != "exact":
-            ap.error("--device-corner requires --pim exact (only the "
-                     "bit-exact accelerator simulation models the analog "
-                     "array)")
         cfg = dataclasses.replace(cfg, pim_crossbar_backend="nonideal",
                                   pim_device_corner=args.device_corner,
                                   pim_device_seed=args.device_seed)
         print(f"device corner: {args.device_corner} "
               f"(die seed {args.device_seed}, nonideal ReRAM array)")
     if args.pim_slicing is not None:
-        if cfg.pim_mode == "off":
-            ap.error("--pim-slicing requires --pim fast|exact|int8 "
-                     "(the float path has no compile step)")
         slicing = args.pim_slicing if args.pim_slicing == "adaptive" \
             else tuple(int(b) for b in args.pim_slicing.split(","))
         cfg = dataclasses.replace(cfg, pim_weight_slicing=slicing)
     params, _ = T.init_params(cfg, jax.random.key(0))
-    max_len = args.prompt_len + args.steps + 1
 
     plans = None
     if cfg.pim_mode != "off":
@@ -166,49 +220,51 @@ def main() -> None:
                 err = "-" if sp.error is None else f"{sp.error:.4f}"
                 print(f"  {sp.site:36s} {'-'.join(map(str, sp.slicing)):16s}"
                       f" err={err}")
+    return Setup(cfg=cfg, params=params, plans=plans)
 
-    if args.engine == "lockstep":
-        eng = ServeEngine(cfg, params, max_len=max_len,
-                          temperature=args.temperature, plans=plans)
-        prompts = np.asarray(jax.random.randint(
-            jax.random.key(1), (args.requests, args.prompt_len), 0,
-            cfg.vocab_size))
-        t0 = time.monotonic()
-        res = eng.generate(prompts, steps=args.steps)
-        dt = time.monotonic() - t0
-        print(f"{cfg.name} lockstep: generated {res.tokens.shape} in "
-              f"{dt:.2f}s ({args.requests * args.steps / dt:.1f} tok/s)")
-        print(res.tokens[:2])
-        return
 
+def max_len_for(args: argparse.Namespace) -> int:
+    max_len = args.prompt_len + args.steps + 1
+    if args.engine == "paged":
+        max_len = -(-max_len // args.block_size) * args.block_size
+    return max_len
+
+
+def make_engine(args: argparse.Namespace, s: Setup):
+    """The continuous or paged engine ``args`` names, with telemetry."""
     tel = obs.ServeTelemetry(engine=args.engine,
                              tracing=args.trace_out is not None,
                              profile_dir=args.profile_dir)
+    if args.engine == "paged":
+        return PagedServeEngine(s.cfg, s.params, n_slots=args.slots,
+                                max_len=max_len_for(args),
+                                prefill_chunk=args.prefill_chunk,
+                                block_size=args.block_size,
+                                n_blocks=args.blocks, plans=s.plans,
+                                telemetry=tel)
+    return ContinuousServeEngine(s.cfg, s.params, n_slots=args.slots,
+                                 max_len=max_len_for(args),
+                                 prefill_chunk=args.prefill_chunk,
+                                 plans=s.plans, telemetry=tel)
+
+
+def run(args: argparse.Namespace, s: Setup, eng) -> list:
+    """Serve the seeded trace on ``eng`` and print the run's report."""
+    cfg, tel = s.cfg, eng.tel
     trace = build_trace(args.requests, prompt_len=args.prompt_len,
                         steps=args.steps, vocab=cfg.vocab_size)
     for i, r in enumerate(trace):
         trace[i] = dataclasses.replace(r, temperature=args.temperature)
-    if args.engine == "paged":
-        max_len = -(-max_len // args.block_size) * args.block_size
-        eng = PagedServeEngine(cfg, params, n_slots=args.slots,
-                               max_len=max_len,
-                               prefill_chunk=args.prefill_chunk,
-                               block_size=args.block_size,
-                               n_blocks=args.blocks, plans=plans,
-                               telemetry=tel)
-    else:
-        eng = ContinuousServeEngine(cfg, params, n_slots=args.slots,
-                                    max_len=max_len,
-                                    prefill_chunk=args.prefill_chunk,
-                                    plans=plans, telemetry=tel)
     t0 = time.monotonic()
     with tel.profile():
         outs = eng.run(trace)
     dt = time.monotonic() - t0
     total = sum(len(o.tokens) for o in outs)
     st = eng.stats
+    dev = jax.devices()[0]
     print(f"{cfg.name} {args.engine}: {len(outs)} requests, {total} tokens in "
-          f"{dt:.2f}s ({total / dt:.1f} tok/s)")
+          f"{dt:.2f}s on {dev.platform} {dev.device_kind}, compiles "
+          f"included ({total / dt:.1f} tok/s)")
     print(f"decode utilization {st.decode_utilization:.2f} tokens/step over "
           f"{args.slots} slots ({st.decode_steps} decode steps, "
           f"{st.prefill_chunks} prefill chunks)")
@@ -218,6 +274,14 @@ def main() -> None:
               f"{st.prefix_block_hits} prefix hits, {st.evictions} "
               f"evictions, {st.admission_waits} admission waits")
     tel.record_stats(st)
+    if cfg.pim_mode == "exact":
+        converts, tokens, fails = (
+            int(tel.registry.counter(f"repro_pim_{k}_total", "",
+                                     ("engine",)).get(engine=tel.engine))
+            for k in ("adc_converts", "decode_tokens", "spec_failures"))
+        print(f"pim exact decode: {converts} converts over {tokens} tokens "
+              f"({converts / max(tokens, 1):.1f} converts/token, {fails} "
+              f"speculation failures)")
     if args.metrics_out:
         obs.write_metrics(tel.registry, args.metrics_out,
                           config={"arch": cfg.name, "engine": args.engine,
@@ -233,6 +297,31 @@ def main() -> None:
     if args.profile_dir:
         print(f"jax profiler trace -> {args.profile_dir}")
     print("first outputs:", {o.uid: o.tokens[:8].tolist() for o in outs[:2]})
+    return outs
+
+
+def main(argv: list[str] | None = None) -> list:
+    args = parse_args(argv)
+    use_compile_cache()
+    rep = device_report(args.kernel_backend)
+    print(f"device: {rep['platform']} {rep['kind']} x{rep['count']}; "
+          f"kernels: " + ", ".join(f"{op} -> {be}"
+                                   for op, be in rep["kernels"].items()))
+    s = setup(args)
+    if args.engine == "lockstep":
+        eng = ServeEngine(s.cfg, s.params, max_len=max_len_for(args),
+                          temperature=args.temperature, plans=s.plans)
+        prompts = np.asarray(jax.random.randint(
+            jax.random.key(1), (args.requests, args.prompt_len), 0,
+            s.cfg.vocab_size))
+        t0 = time.monotonic()
+        res = eng.generate(prompts, steps=args.steps)
+        dt = time.monotonic() - t0
+        print(f"{s.cfg.name} lockstep: generated {res.tokens.shape} in "
+              f"{dt:.2f}s ({args.requests * args.steps / dt:.1f} tok/s)")
+        print(res.tokens[:2])
+        return []
+    return run(args, s, make_engine(args, s))
 
 
 if __name__ == "__main__":

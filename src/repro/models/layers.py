@@ -192,8 +192,8 @@ def pim_matmul(x: jnp.ndarray, w: jnp.ndarray, plan,
     from ``repro.models.pim.prepare_pim_params`` (``None`` -> float path:
     training, rwkv blocks, or ``pim_mode == 'off'``). Modes:
 
-      fast  — centered int8 MXU matmul (paper Eq. 1; Pallas kernel when
-              ``cfg.pim_use_pallas``, XLA fallback otherwise).
+      fast  — centered int8 MXU matmul (paper Eq. 1; the kernel backend
+              ``cfg.pim_kernel_backend`` names, see ``repro.kernels.ops``).
       exact — bit-exact accelerator simulation (Center+Offset, sliced
               crossbars, ADC, speculation) via ``pim_linear.forward_exact``.
       int8  — ideal 8b-quantized reference (``forward_int_reference``);
@@ -209,7 +209,7 @@ def pim_matmul(x: jnp.ndarray, w: jnp.ndarray, plan,
     xb = x.reshape(-1, x.shape[-1]).astype(jnp.float32)
     pp = _plan_to_pim_plan(plan, cfg, rows=w.shape[0])
     if cfg.pim_mode == "fast":
-        y = pim_linear.forward_fast(xb, pp, use_pallas=cfg.pim_use_pallas)
+        y = pim_linear.forward_fast(xb, pp)
     elif cfg.pim_mode == "exact":
         if pim_stats_active():
             y, st = pim_linear.forward_exact(xb, pp, return_stats=True)

@@ -25,6 +25,20 @@ from repro.configs.base import ArchConfig
 from repro.models import transformer as T
 
 
+def jit_step(fn):
+    """``jax.jit`` for a serving step, with XLA's excess precision off.
+
+    With it on, XLA may keep a bf16 intermediate in f32 inside a fusion,
+    so a step's numerics depend on where fusions end. A Pallas kernel
+    ends fusions where its XLA reference does not, and the int8
+    quantizer of the ``fast`` path turns those last-bit differences into
+    whole-code flips: on a TPU v5e, qwen1.5-0.5b's logits differed by 6%
+    between the two backends of a bit-exact kernel. Off, every bf16
+    value is rounded where the program says, so backends whose kernels
+    agree bit for bit give identical steps."""
+    return jax.jit(fn, compiler_options={"xla_allow_excess_precision": False})
+
+
 @dataclasses.dataclass
 class GenerationResult:
     tokens: np.ndarray        # (B, steps) generated ids
@@ -48,10 +62,10 @@ class ServeEngine:
         self.plans = plans
         self.max_len = max_len
         self.temperature = temperature
-        self._prefill = jax.jit(
+        self._prefill = jit_step(
             lambda p, pl, toks: T.prefill(p, cfg, toks, max_len=max_len,
                                           plans=pl))
-        self._decode = jax.jit(
+        self._decode = jit_step(
             lambda p, pl, st, tok: T.decode_step(p, cfg, st, tok, plans=pl))
 
     def _pick(self, logits: jnp.ndarray, key) -> jnp.ndarray:

@@ -57,6 +57,7 @@ from repro.configs.base import ArchConfig
 from repro.dist import MULTIPOD_SERVE_RULES, axis_rules
 from repro.models import transformer as T
 from repro.obs.serve import NULL_TELEMETRY
+from repro.serve.engine import jit_step
 from repro.serve.scheduler import (
     ContinuousServeEngine,
     RequestOutput,
@@ -212,17 +213,17 @@ class PagedServeEngine(ContinuousServeEngine):
                               np.int32)
 
         layout = self.layout
-        self._chunk = jax.jit(
+        self._chunk = jit_step(
             lambda p, pl, st, toks: T.prefill_chunk(p, cfg, st, toks,
                                                     plans=pl))
-        self._chunk_paged = jax.jit(
+        self._chunk_paged = jit_step(
             lambda p, pl, st, toks, slot, row, pos0: T.prefill_chunk_paged(
                 p, cfg, st, toks, slot=slot, table_row=row, pos0=pos0,
                 paged=layout, plans=pl))
-        self._decode = jax.jit(self._wrap_decode(
+        self._decode = jit_step(self._wrap_decode(
             lambda p, pl, st, tok, tb: T.decode_step(
                 p, cfg, st, tok, plans=pl, block_tables=tb, paged=layout)))
-        self._insert = jax.jit(
+        self._insert = jit_step(
             lambda st, one, slot, row: T.insert_request_paged(
                 st, one, slot, row, layout))
 

@@ -56,6 +56,7 @@ from repro.configs.base import ArchConfig
 from repro.models import layers as L
 from repro.models import transformer as T
 from repro.obs.serve import NULL_TELEMETRY, ServeTelemetry
+from repro.serve.engine import jit_step
 
 
 @dataclasses.dataclass(frozen=True)
@@ -165,12 +166,12 @@ class ContinuousServeEngine:
         self.queue: collections.deque[Request] = collections.deque()
         self.stats = ServeStats()
         self.tel = telemetry if telemetry is not None else NULL_TELEMETRY
-        self._chunk = jax.jit(
+        self._chunk = jit_step(
             lambda p, pl, st, toks: T.prefill_chunk(p, cfg, st, toks,
                                                     plans=pl))
-        self._decode = jax.jit(self._wrap_decode(
+        self._decode = jit_step(self._wrap_decode(
             lambda p, pl, st, tok: T.decode_step(p, cfg, st, tok, plans=pl)))
-        self._insert = jax.jit(
+        self._insert = jit_step(
             lambda st, one, slot: T.insert_request(st, one, slot))
         # jax arrays are immutable, so one zero template serves every
         # admission (prefill_chunk returns fresh state pytrees)

@@ -1,5 +1,5 @@
-"""Shared test setup: fake multi-device CPU, jax compat shims, hypothesis
-fallback, and the fixed-seed RNG / small-mesh fixtures."""
+"""Shared test setup: fake multi-device CPU, hypothesis fallback, and the
+fixed-seed RNG / small-mesh fixtures."""
 
 import os
 import sys
@@ -19,11 +19,11 @@ from repro import testing as repro_testing  # noqa: E402
 
 repro_testing.install_hypothesis_fallback()
 
-import repro.dist  # noqa: E402,F401  (installs jax API compat shims)
-
 import jax  # noqa: E402
 import numpy as np  # noqa: E402
 import pytest  # noqa: E402
+
+from repro.launch.mesh import make_test_mesh  # noqa: E402
 
 
 @pytest.fixture
@@ -34,10 +34,12 @@ def rng():
 
 @pytest.fixture
 def small_mesh():
-    """Concrete 2x2 ("data", "model") mesh over fake CPU devices."""
+    """Concrete 2x2 ("data", "model") mesh with Auto axes over fake CPU
+    devices (``jax.make_mesh`` defaults to Explicit axes, under which
+    ``with_sharding_constraint`` refuses a logical-rule mesh)."""
     if jax.device_count() < 4:
         pytest.skip("needs 4 (fake) devices")
-    return jax.make_mesh((2, 2), ("data", "model"))
+    return make_test_mesh(2, 2)
 
 
 @pytest.fixture

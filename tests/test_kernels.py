@@ -22,7 +22,7 @@ class TestCenteredInt8Matmul:
         x = jnp.asarray(rng.integers(-127, 128, (B, K)), jnp.int8)
         w = jnp.asarray(rng.integers(-127, 128, (K, N)), jnp.int8)
         c = jnp.asarray(rng.integers(-128, 128, (N,)), jnp.int32)
-        got = ops.centered_int8_matmul(x, w, c, use_pallas=True)
+        got = ops.centered_int8_matmul(x, w, c, backend="interpret")
         want = ref.centered_int8_matmul(x, w, c)
         np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
@@ -46,7 +46,7 @@ class TestCenteredInt8Matmul:
         w_off = (w_full - c[None, :]).astype(np.int8)
         x = jnp.asarray(rng.integers(-127, 128, (9, 64)), jnp.int8)
         got = ops.centered_int8_matmul(x, jnp.asarray(w_off), jnp.asarray(c),
-                                       use_pallas=True)
+                                       backend="interpret")
         want = np.asarray(x, np.int64) @ w_full
         np.testing.assert_array_equal(np.asarray(got, np.int64), want)
 
@@ -59,7 +59,7 @@ class TestCenteredInt8Matmul:
         x = jnp.asarray(rng.integers(-127, 128, (B, K)), jnp.int8)
         w = jnp.asarray(rng.integers(-127, 128, (K, N)), jnp.int8)
         c = jnp.asarray(rng.integers(-128, 128, (N,)), jnp.int32)
-        got = ops.centered_int8_matmul(x, w, c, use_pallas=True)
+        got = ops.centered_int8_matmul(x, w, c, backend="interpret")
         np.testing.assert_array_equal(
             np.asarray(got), np.asarray(ref.centered_int8_matmul(x, w, c)))
 
@@ -79,7 +79,7 @@ class TestSlicedCrossbarKernel:
     def test_shapes(self, n_i, n_j, B, R, C):
         rng = np.random.default_rng(n_i + 10 * n_j + B + R + C)
         xs, wp, m = self._mk(rng, n_i, n_j, B, R, C)
-        got = ops.sliced_crossbar_matmul(xs, wp, m, use_pallas=True)
+        got = ops.sliced_crossbar_matmul(xs, wp, m, backend="interpret")
         want = ref.sliced_crossbar_matmul(xs, wp, m)
         np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
@@ -89,7 +89,7 @@ class TestSlicedCrossbarKernel:
         xs, wp, m = self._mk(rng, 1, 1, 2, 1024, 8)
         xs = jnp.full_like(xs, 15)
         wp = jnp.full_like(wp, 15)
-        got = ops.sliced_crossbar_matmul(xs, wp, m, use_pallas=True)
+        got = ops.sliced_crossbar_matmul(xs, wp, m, backend="interpret")
         # two segments, each clamps at 63 -> 126 * mult
         np.testing.assert_array_equal(np.asarray(got),
                                       np.full((2, 8), 126 * int(m[0, 0])))
@@ -115,7 +115,7 @@ class TestSlicedCrossbarKernel:
             [[1 << (li + lw) for lw in enc.shifts] for li in range(7, -1, -1)],
             jnp.int32)
         offs = ops.sliced_crossbar_matmul(x_slices, w_planes, mults,
-                                          use_pallas=True)
+                                          backend="interpret")
         got = offs + co.center_term(x, enc)
         np.testing.assert_array_equal(np.asarray(got), np.asarray(psum))
 
@@ -127,7 +127,7 @@ class TestSlicedCrossbarKernel:
         B, R, C = (int(rng.integers(1, 9)), int(rng.integers(1, 1200)),
                    int(rng.integers(1, 150)))
         xs, wp, m = self._mk(rng, n_i, n_j, B, R, C)
-        got = ops.sliced_crossbar_matmul(xs, wp, m, use_pallas=True)
+        got = ops.sliced_crossbar_matmul(xs, wp, m, backend="interpret")
         want = ref.sliced_crossbar_matmul(xs, wp, m)
         np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
@@ -158,7 +158,7 @@ class TestEdgeShapesBothPaths:
                     out += np.clip(cs, lo, hi) * int(m[i, j])
         return out
 
-    @pytest.mark.parametrize("use_pallas", [True, False],
+    @pytest.mark.parametrize("backend", ["interpret", "xla"],
                              ids=["interpret", "xla-fallback"])
     @pytest.mark.parametrize("B,K,N", [
         (1, 1, 1),       # full singleton
@@ -167,16 +167,16 @@ class TestEdgeShapesBothPaths:
         (2, 130, 257),   # N not a multiple of the 128 tile
         (9, 1, 130),     # K=1 (degenerate contraction)
     ])
-    def test_centered_int8_edges(self, B, K, N, use_pallas):
+    def test_centered_int8_edges(self, B, K, N, backend):
         rng = np.random.default_rng(B * 7919 + K * 31 + N)
         x = jnp.asarray(rng.integers(-127, 128, (B, K)), jnp.int8)
         w = jnp.asarray(rng.integers(-127, 128, (K, N)), jnp.int8)
         c = jnp.asarray(rng.integers(-128, 128, (N,)), jnp.int32)
-        got = ops.centered_int8_matmul(x, w, c, use_pallas=use_pallas)
+        got = ops.centered_int8_matmul(x, w, c, backend=backend)
         np.testing.assert_array_equal(np.asarray(got, np.int64),
                                       self._np_centered(x, w, c))
 
-    @pytest.mark.parametrize("use_pallas", [True, False],
+    @pytest.mark.parametrize("backend", ["interpret", "xla"],
                              ids=["interpret", "xla-fallback"])
     @pytest.mark.parametrize("n_i,n_j,B,R,C", [
         (1, 1, 1, 1, 1),      # minimal everything
@@ -184,13 +184,13 @@ class TestEdgeShapesBothPaths:
         (2, 3, 1, 700, 130),  # B=1, R and C both off-tile
         (1, 2, 4, 1025, 1),   # C=1, R spills into a third segment
     ])
-    def test_sliced_crossbar_edges(self, n_i, n_j, B, R, C, use_pallas):
+    def test_sliced_crossbar_edges(self, n_i, n_j, B, R, C, backend):
         rng = np.random.default_rng(n_i * 131 + n_j * 17 + B + R + C)
         xs = jnp.asarray(rng.integers(0, 16, (n_i, B, R)), jnp.int8)
         wp = jnp.asarray(rng.integers(-15, 16, (n_j, R, C)), jnp.int8)
         m = jnp.asarray(rng.choice([1, 2, 4, 16, 64], size=(n_i, n_j)),
                         jnp.int32)
-        got = ops.sliced_crossbar_matmul(xs, wp, m, use_pallas=use_pallas)
+        got = ops.sliced_crossbar_matmul(xs, wp, m, backend=backend)
         np.testing.assert_array_equal(np.asarray(got, np.int64),
                                       self._np_sliced(xs, wp, m))
 
@@ -200,9 +200,9 @@ class TestEdgeShapesBothPaths:
         xs = jnp.full((1, 2, 700), 15, jnp.int8)
         wp = jnp.full((1, 700, 4), 15, jnp.int8)
         m = jnp.ones((1, 1), jnp.int32)
-        for use_pallas in (True, False):
+        for backend in ("interpret", "xla"):
             got = ops.sliced_crossbar_matmul(xs, wp, m,
-                                             use_pallas=use_pallas)
+                                             backend=backend)
             # both segments (512 rows + 188-row tail) saturate at 63
             np.testing.assert_array_equal(np.asarray(got),
                                           np.full((2, 4), 126))

@@ -325,10 +325,12 @@ class TestBackendRegistry:
         # auto on the CPU test host -> the XLA reference
         assert ops.resolve_backend("fused_crossbar") == "xla"
         assert ops.resolve_backend("fused_crossbar", "auto") == "xla"
-        # 'pallas' alias: interpret off-TPU (legacy use_pallas semantics)
-        assert ops.resolve_backend("fused_crossbar", "pallas") == "interpret"
-        # unregistered backend falls back to the XLA reference
-        assert ops.resolve_backend("fused_crossbar", "pallas-gpu") == "xla"
+        # the removed 'pallas' alias and the never-registered
+        # 'pallas-gpu' raise: no request falls silently to another backend
+        with pytest.raises(ValueError, match="not registered"):
+            ops.resolve_backend("fused_crossbar", "pallas")
+        with pytest.raises(ValueError, match="not registered"):
+            ops.resolve_backend("fused_crossbar", "pallas-gpu")
 
     def test_env_override_wins(self):
         prev = os.environ.get(ops.ENV_VAR)

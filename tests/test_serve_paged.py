@@ -300,6 +300,29 @@ def test_disaggregated_prefill_decode_slices():
     assert eng.stats.blocks_in_use == 0
 
 
+def test_disaggregated_fast_pim_runs_kernels_under_meshes():
+    """The fast path's Pallas kernel inside both mesh slices: GSPMD
+    cannot partition a Mosaic kernel, so the registry runs it whole on
+    every device of the slice. Greedy tokens match the one-device engine
+    on the same plans."""
+    from repro.models import pim
+    cfg, params = setup("yi-6b")
+    cfg = dataclasses.replace(cfg, pim_mode="fast",
+                              pim_kernel_backend="interpret")
+    calib = np.asarray(jax.random.randint(jax.random.key(7), (2, 8), 0,
+                                          cfg.vocab_size))
+    plans = pim.compile_pim_params(params, cfg, calib).plans
+    reqs = mixed_requests(cfg, n=2)
+    kw = dict(n_slots=2, max_len=16, prefill_chunk=4, block_size=4,
+              plans=plans)
+    want = PagedServeEngine(cfg, params, **kw).run(reqs)
+    pm, dm = make_disaggregated_meshes()
+    got = PagedServeEngine(cfg, params, prefill_mesh=pm, decode_mesh=dm,
+                           **kw).run(reqs)
+    for a, b in zip(got, want):
+        assert np.array_equal(a.tokens, b.tokens), f"uid {a.uid}"
+
+
 def test_disaggregated_mesh_validation():
     with pytest.raises(ValueError, match="devices"):
         make_disaggregated_meshes(prefill=(2, 16, 16), decode=(2, 16, 16))
