@@ -1,0 +1,6 @@
+"""The mean gap, over every sampled served position, by which the served
+token's logit lies below the reference's best."""
+
+
+def read(gaps):
+    return float(gaps.mean())
