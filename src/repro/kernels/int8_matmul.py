@@ -83,6 +83,7 @@ def centered_int8_matmul(x_q: jnp.ndarray, w_off: jnp.ndarray,
             pltpu.VMEM((bm, 1), jnp.int32),
         ],
         interpret=interpret,
+        name="centered_int8_matmul",
     )(x_p, w_p, c_p)
     return out[:B, :N]
 

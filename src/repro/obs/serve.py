@@ -27,16 +27,20 @@ request from scratch — its replay re-observes queue-wait/TTFT (each
 observation is one *scheduling attempt*), while ``requests_completed``
 counts the request once.
 
-``jax.profiler`` hooks: with ``profile_dir`` set, ``profile()`` wraps a
-run in ``start_trace``/``stop_trace`` and ``annotate_step`` marks each
-jitted decode/prefill dispatch with a ``StepTraceAnnotation`` so device
-profiles line up with scheduler iterations. Both are inert when
-``profile_dir`` is ``None``.
+``jax.profiler`` hooks: an enabled telemetry puts every ``span`` on the
+profiler's host plane as ``serve.<name>`` (a ``TraceAnnotation``, on the
+device trace's clock) and marks each jitted decode/prefill dispatch with
+a ``StepTraceAnnotation`` (``annotate_step``), so each idle gap of a
+device profile names the scheduler phase the host was in. Both cost an
+inactive ``TraceMe`` when no profiler runs. ``profile_dir`` only decides
+whether ``profile()`` starts and stops a profile itself.
 """
 
 from __future__ import annotations
 
 import contextlib
+
+import jax.profiler
 
 from repro.core import energy as en
 from repro.obs.metrics import MetricsRegistry
@@ -177,9 +181,10 @@ class ServeTelemetry:
             ("engine",))
         self._step_time = r.histogram(
             "repro_serve_decode_step_seconds",
-            "host wall time of one batched decode step (dispatch + the "
-            "iteration's one device_get)", ("engine",),
+            "host wall time of one batched decode step (dispatch, logits "
+            "fetch, host sampling and commit)", ("engine",),
             buckets=STEP_BUCKETS)
+        self._profiler_names: dict[str, str] = {}
         self.tracer.name_track(ENGINE_TID, f"{engine} engine")
 
     # ------------------------------------------------------------ helpers
@@ -190,7 +195,31 @@ class ServeTelemetry:
         return uid + 1
 
     def span(self, name: str, **args):
-        return self.tracer.span(name, **args)
+        """Context manager around one scheduler phase: a
+        ``TraceAnnotation`` named ``serve.<name>`` on the profiler's host
+        plane (inert unless a profile runs), plus a Chrome-trace event
+        with ``args`` when the tracer is on. ``args`` never reach the
+        profiler, so its span names stay fixed."""
+        pname = self._profiler_names.get(name)
+        if pname is None:
+            pname = self._profiler_names[name] = f"serve.{name}"
+        if name != "decode_step" and not self.tracer.enabled:
+            return jax.profiler.TraceAnnotation(pname)
+        return self._timed_span(name, pname, args)
+
+    @contextlib.contextmanager
+    def _timed_span(self, name, pname, args):
+        """The annotation, timed: the Chrome event (a no-op with the
+        tracer off) and, for ``decode_step``, its histogram."""
+        with jax.profiler.TraceAnnotation(pname):
+            t0 = self.tracer.now()
+            try:
+                yield
+            finally:
+                dur = self.tracer.now() - t0
+                self.tracer.complete(name, t0, dur, **args)
+                if name == "decode_step":
+                    self._step_time.observe(dur * 1e-6, **self._lab)
 
     # --------------------------------------------------------- lifecycle
     def on_submit(self, uid: int) -> None:
@@ -235,9 +264,6 @@ class ServeTelemetry:
     def on_decode_step(self, n_live: int) -> None:
         self._decode_steps.inc(**self._lab)
         self._slot_tokens.inc(n_live, **self._lab)
-
-    def observe_decode_step_seconds(self, dt: float) -> None:
-        self._step_time.observe(dt, **self._lab)
 
     def on_token(self, uid: int) -> None:
         """One committed token for ``uid`` — first-vs-subsequent decides
@@ -288,10 +314,7 @@ class ServeTelemetry:
     # --------------------------------------------------------- profiling
     def annotate_step(self, name: str, step: int):
         """``jax.profiler.StepTraceAnnotation`` around a jitted dispatch
-        when device profiling is configured; inert otherwise."""
-        if self.profile_dir is None:
-            return contextlib.nullcontext()
-        import jax.profiler
+        (inert unless a profile runs)."""
         return jax.profiler.StepTraceAnnotation(name, step_num=step)
 
     @contextlib.contextmanager
@@ -301,7 +324,6 @@ class ServeTelemetry:
         if self.profile_dir is None:
             yield
             return
-        import jax.profiler
         jax.profiler.start_trace(self.profile_dir)
         try:
             yield
@@ -309,10 +331,13 @@ class ServeTelemetry:
             jax.profiler.stop_trace()
 
 
+_NO_SPAN = contextlib.nullcontext()
+
+
 class _NullTelemetry(ServeTelemetry):
     """Hook-compatible no-op: every metric write hits the disabled
-    registry's shared null metric and every span is a disabled-tracer
-    pass-through, so engines call hooks unconditionally."""
+    registry's shared null metric and every span or step annotation is
+    one shared null context, so engines call hooks unconditionally."""
 
     enabled = False
 
@@ -332,8 +357,11 @@ class _NullTelemetry(ServeTelemetry):
     def on_finish(self, uid, reason, n_tokens):
         pass
 
-    def observe_decode_step_seconds(self, dt):
-        pass
+    def span(self, name, **args):
+        return _NO_SPAN
+
+    def annotate_step(self, name, step):
+        return _NO_SPAN
 
 
 NULL_TELEMETRY = _NullTelemetry()

@@ -15,7 +15,9 @@ array Perfetto / ``chrome://tracing`` load directly):
 - one metadata event per track naming the lane.
 
 Track convention (see ``docs/observability.md`` for the span taxonomy):
-``tid 0`` is the engine lane (admission / prefill_chunk / decode_step);
+``tid 0`` is the engine lane (the scheduler phases: step / admission /
+prefill_chunk / first_token / grow_blocks / decode_inputs / decode_step
+with its fetch / sample / commit);
 each request gets its own lane at ``tid = uid + 1`` (queue_wait /
 request / instants), so a Perfetto timeline shows scheduler occupancy
 above a per-request Gantt chart.

@@ -46,7 +46,6 @@ from __future__ import annotations
 import collections
 import contextlib
 import dataclasses
-import time
 from typing import Any
 
 import jax
@@ -370,96 +369,109 @@ class PagedServeEngine(ContinuousServeEngine):
         """One iteration: admit (FIFO, queue-until-blocks-free) → prefill
         one chunk per admitted-but-not-live slot → one batched paged
         decode for live slots (lazy block growth, eviction under
-        pressure). Host syncs are batched as in the parent engine."""
+        pressure). Host syncs are batched as in the parent engine; the
+        telemetry spans name the same phases as the parent's ``step``,
+        plus ``grow_blocks``."""
+        tel = self.tel
         finished: list[RequestOutput] = []
-        # 1. admission
-        with self.tel.span("admission"):
-            free_idx = [i for i, s in enumerate(self.slots) if s is None]
-            while free_idx and self.queue:
-                if not self._try_admit(free_idx[0]):
-                    break                   # head waits; FIFO holds
-                free_idx.pop(0)
-        # 2. prefill: one chunk per mid-prefill slot
-        done: list[tuple[int, _PagedSlot, Any]] = []
-        for i, slot in enumerate(self.slots):
-            if slot is None or slot.live:
-                continue
-            prompt = np.asarray(slot.req.prompt, np.int32)
-            lo, hi = slot.n_prefilled, min(slot.n_prefilled
-                                           + self.prefill_chunk,
-                                           prompt.shape[0])
-            toks = jnp.asarray(prompt[None, lo:hi])
-            with self.tel.span("prefill_chunk", uid=slot.req.uid,
-                               lo=lo, hi=hi), \
-                    self.tel.annotate_step("prefill_chunk",
-                                           self.stats.prefill_chunks):
-                if self.staged_prefill:
-                    with self._on(self.prefill_mesh):
-                        logits, slot.state1 = self._chunk(
-                            self._params_p, self._plans_p, slot.state1, toks)
-                else:
-                    with self._on(self.decode_mesh):
-                        logits, self.state = self._chunk_paged(
-                            self._params_d, self._plans_d, self.state, toks,
-                            jnp.asarray(i, jnp.int32),
-                            jnp.asarray(self.tables[i]),
-                            jnp.asarray(lo, jnp.int32))
-            slot.n_prefilled = hi
-            self.stats.prefill_chunks += 1
-            self.tel.on_prefill_chunk(slot.req.uid, lo, hi)
-            if hi == prompt.shape[0]:
-                if self.staged_prefill:
-                    one = slot.state1
-                    if self._rep_d is not None:   # hand blocks to the
-                        one = jax.device_put(one, self._rep_d)  # decode slice
-                    with self._on(self.decode_mesh):
-                        self.state = self._insert(
-                            self.state, one, jnp.asarray(i, jnp.int32),
-                            jnp.asarray(self.tables[i]))
-                    slot.state1 = None
-                slot.live = True
-                slot.host_pos = hi
-                self._register_prompt_blocks(slot)
-                done.append((i, slot, logits[0, -1]))
-        if done:
-            rows = jax.device_get([lg for _, _, lg in done])
-            for (i, slot, _), row in zip(done, rows):
-                self._commit(i, slot,
-                             self._sample(slot, row, int(np.argmax(row))),
-                             finished)
-        # 3. batched paged decode over live slots
-        live = [i for i, s in enumerate(self.slots)
-                if s is not None and s.live]
-        for i in sorted(live, key=lambda j: self.slots[j].seq):
-            if self.slots[i] is not None:   # an eviction may have taken it
-                self._ensure_decode_block(i)
-        live = [i for i, s in enumerate(self.slots)
-                if s is not None and s.live]
-        if live:
-            toks = np.zeros((self.n_slots, 1), np.int32)
-            tables = np.full_like(self.tables, self.layout.sentinel)
-            for i in live:
-                toks[i, 0] = self.slots[i].next_tok
-                tables[i] = self.tables[i]  # non-live rows stay sentinel
-            with self.tel.span("decode_step", n_live=len(live)):
-                t0 = time.perf_counter()
-                with self.tel.annotate_step("decode_step",
-                                            self.stats.decode_steps), \
-                        self._on(self.decode_mesh):
-                    out = self._decode(
-                        self._params_d, self._plans_d, self.state,
-                        jnp.asarray(toks), jnp.asarray(tables))
-                rows, self.state = self._decode_fetch(out, len(live))
-                self.tel.observe_decode_step_seconds(
-                    time.perf_counter() - t0)
-                self.stats.decode_steps += 1
-                self.stats.decode_slot_tokens += len(live)
-                self.tel.on_decode_step(len(live))
-                greedy = np.argmax(rows, axis=-1)
-                for i in live:
-                    slot = self.slots[i]
-                    slot.host_pos += 1
-                    self._commit(i, slot,
-                                 self._sample(slot, rows[i],
-                                              int(greedy[i])), finished)
+        with tel.span("step"):
+            # 1. admission
+            with tel.span("admission"):
+                free_idx = [i for i, s in enumerate(self.slots) if s is None]
+                while free_idx and self.queue:
+                    if not self._try_admit(free_idx[0]):
+                        break               # head waits; FIFO holds
+                    free_idx.pop(0)
+            # 2. prefill: one chunk per mid-prefill slot
+            done: list[tuple[int, _PagedSlot, Any]] = []
+            for i, slot in enumerate(self.slots):
+                if slot is None or slot.live:
+                    continue
+                prompt = np.asarray(slot.req.prompt, np.int32)
+                lo, hi = slot.n_prefilled, min(slot.n_prefilled
+                                               + self.prefill_chunk,
+                                               prompt.shape[0])
+                with tel.span("prefill_chunk", uid=slot.req.uid, lo=lo,
+                              hi=hi):
+                    self._prefill_one(i, slot, prompt, lo, hi, done)
+            if done:
+                with tel.span("first_token"):
+                    rows = jax.device_get([lg for _, _, lg in done])
+                    for (i, slot, _), row in zip(done, rows):
+                        self._commit(i, slot, self._sample(
+                            slot, row, int(np.argmax(row))), finished)
+            # 3. batched paged decode over live slots
+            live = [i for i, s in enumerate(self.slots)
+                    if s is not None and s.live]
+            if live:
+                with tel.span("grow_blocks"):
+                    for i in sorted(live, key=lambda j: self.slots[j].seq):
+                        # an eviction may have taken it
+                        if self.slots[i] is not None:
+                            self._ensure_decode_block(i)
+                    live = [i for i, s in enumerate(self.slots)
+                            if s is not None and s.live]
+            if live:
+                with tel.span("decode_inputs"):
+                    toks = np.zeros((self.n_slots, 1), np.int32)
+                    tables = np.full_like(self.tables, self.layout.sentinel)
+                    for i in live:
+                        toks[i, 0] = self.slots[i].next_tok
+                        tables[i] = self.tables[i]  # others stay sentinel
+                    toks, tables = jnp.asarray(toks), jnp.asarray(tables)
+                with tel.span("decode_step", n_live=len(live)):
+                    with tel.annotate_step("decode_step",
+                                           self.stats.decode_steps), \
+                            self._on(self.decode_mesh):
+                        out = self._decode(self._params_d, self._plans_d,
+                                           self.state, toks, tables)
+                    with tel.span("fetch"):
+                        rows, self.state = self._decode_fetch(out, len(live))
+                    self.stats.decode_steps += 1
+                    self.stats.decode_slot_tokens += len(live)
+                    tel.on_decode_step(len(live))
+                    with tel.span("sample"):
+                        greedy = np.argmax(rows, axis=-1)
+                    with tel.span("commit"):
+                        for i in live:
+                            slot = self.slots[i]
+                            slot.host_pos += 1
+                            self._commit(i, slot, self._sample(
+                                slot, rows[i], int(greedy[i])), finished)
         return finished
+
+    def _prefill_one(self, i: int, slot: _PagedSlot, prompt: np.ndarray,
+                     lo: int, hi: int, done: list) -> None:
+        """Dispatch prompt tokens ``[lo, hi)`` of slot ``i``; a finished
+        prompt goes live and queues its last logits row on ``done``."""
+        toks = jnp.asarray(prompt[None, lo:hi])
+        with self.tel.annotate_step("prefill_chunk",
+                                    self.stats.prefill_chunks):
+            if self.staged_prefill:
+                with self._on(self.prefill_mesh):
+                    logits, slot.state1 = self._chunk(
+                        self._params_p, self._plans_p, slot.state1, toks)
+            else:
+                with self._on(self.decode_mesh):
+                    logits, self.state = self._chunk_paged(
+                        self._params_d, self._plans_d, self.state, toks,
+                        jnp.asarray(i, jnp.int32),
+                        jnp.asarray(self.tables[i]),
+                        jnp.asarray(lo, jnp.int32))
+        slot.n_prefilled = hi
+        self.stats.prefill_chunks += 1
+        self.tel.on_prefill_chunk(slot.req.uid, lo, hi)
+        if hi == prompt.shape[0]:
+            if self.staged_prefill:
+                one = slot.state1
+                if self._rep_d is not None:       # hand blocks to the
+                    one = jax.device_put(one, self._rep_d)  # decode slice
+                with self._on(self.decode_mesh):
+                    self.state = self._insert(
+                        self.state, one, jnp.asarray(i, jnp.int32),
+                        jnp.asarray(self.tables[i]))
+                slot.state1 = None
+            slot.live = True
+            slot.host_pos = hi
+            self._register_prompt_blocks(slot)
+            done.append((i, slot, logits[0, -1]))

@@ -45,7 +45,6 @@ from __future__ import annotations
 
 import collections
 import dataclasses
-import time
 from typing import Any
 
 import jax
@@ -274,73 +273,81 @@ class ContinuousServeEngine:
         ``jax.device_get``, and the decode step pulls every slot's last
         logits row at once (greedy argmax then runs host-side —
         ``np.argmax`` and ``jnp.argmax`` both take the first maximum, so
-        the tie-break is bit-identical).
+        the tie-break is bit-identical). Telemetry spans tile the
+        iteration: ``step`` holds ``admission``, ``prefill_chunk``,
+        ``first_token``, ``decode_inputs`` and ``decode_step``, which
+        holds ``fetch``, ``sample`` and ``commit``.
         """
+        tel = self.tel
         finished: list[RequestOutput] = []
-        # 1. admit queued requests into free slots
-        with self.tel.span("admission"):
-            for i in range(self.n_slots):
-                if self.slots[i] is None and self.queue:
-                    req = self.queue.popleft()
-                    self.slots[i] = _Slot(req=req, state1=self._template1)
-                    self.tel.on_admit(req.uid,
-                                      int(np.asarray(req.prompt).shape[0]))
-        # 2. advance each prefilling slot by one chunk
-        done: list[tuple[int, _Slot, Any]] = []
-        for i, slot in enumerate(self.slots):
-            if slot is None or slot.state1 is None:
-                continue
-            prompt = np.asarray(slot.req.prompt, np.int32)
-            lo = slot.n_prefilled
-            hi = min(lo + self.prefill_chunk, prompt.shape[0])
-            with self.tel.span("prefill_chunk", uid=slot.req.uid,
-                               lo=lo, hi=hi), \
-                    self.tel.annotate_step("prefill_chunk",
+        with tel.span("step"):
+            # 1. admit queued requests into free slots
+            with tel.span("admission"):
+                for i in range(self.n_slots):
+                    if self.slots[i] is None and self.queue:
+                        req = self.queue.popleft()
+                        self.slots[i] = _Slot(req=req,
+                                              state1=self._template1)
+                        tel.on_admit(req.uid,
+                                     int(np.asarray(req.prompt).shape[0]))
+            # 2. advance each prefilling slot by one chunk
+            done: list[tuple[int, _Slot, Any]] = []
+            for i, slot in enumerate(self.slots):
+                if slot is None or slot.state1 is None:
+                    continue
+                prompt = np.asarray(slot.req.prompt, np.int32)
+                lo = slot.n_prefilled
+                hi = min(lo + self.prefill_chunk, prompt.shape[0])
+                with tel.span("prefill_chunk", uid=slot.req.uid, lo=lo,
+                              hi=hi):
+                    toks = jnp.asarray(prompt[None, lo:hi])
+                    with tel.annotate_step("prefill_chunk",
                                            self.stats.prefill_chunks):
-                logits, slot.state1 = self._chunk(
-                    self.params, self.plans, slot.state1,
-                    jnp.asarray(prompt[None, lo:hi]))
-            slot.n_prefilled = hi
-            self.stats.prefill_chunks += 1
-            self.tel.on_prefill_chunk(slot.req.uid, lo, hi)
-            if hi == prompt.shape[0]:
-                # prompt done: splice into the batch; first-token logits
-                # are committed below, after ONE batched device_get
-                self.state = self._insert(self.state, slot.state1,
-                                          jnp.asarray(i, jnp.int32))
-                slot.state1 = None
-                done.append((i, slot, logits[0, -1]))
-        if done:
-            rows = jax.device_get([lg for _, _, lg in done])
-            for (i, slot, _), row in zip(done, rows):
-                self._commit(i, slot,
-                             self._sample(slot, row, int(np.argmax(row))),
-                             finished)
-        # 3. one batched decode step for every mid-generation slot
-        live = [i for i, s in enumerate(self.slots)
-                if s is not None and s.state1 is None]
-        if live:
-            toks = np.zeros((self.n_slots, 1), np.int32)
-            for i in live:
-                toks[i, 0] = self.slots[i].next_tok
-            with self.tel.span("decode_step", n_live=len(live)):
-                t0 = time.perf_counter()
-                with self.tel.annotate_step("decode_step",
-                                            self.stats.decode_steps):
-                    out = self._decode(self.params, self.plans, self.state,
-                                       jnp.asarray(toks))
-                rows, self.state = self._decode_fetch(out, len(live))
-                self.tel.observe_decode_step_seconds(
-                    time.perf_counter() - t0)
-                self.stats.decode_steps += 1
-                self.stats.decode_slot_tokens += len(live)
-                self.tel.on_decode_step(len(live))
-                greedy = np.argmax(rows, axis=-1)
-                for i in live:
-                    slot = self.slots[i]
-                    self._commit(i, slot,
-                                 self._sample(slot, rows[i],
-                                              int(greedy[i])), finished)
+                        logits, slot.state1 = self._chunk(
+                            self.params, self.plans, slot.state1, toks)
+                    slot.n_prefilled = hi
+                    self.stats.prefill_chunks += 1
+                    tel.on_prefill_chunk(slot.req.uid, lo, hi)
+                    if hi == prompt.shape[0]:
+                        # prompt done: splice into the batch; first-token
+                        # logits are committed below, after ONE batched
+                        # device_get
+                        self.state = self._insert(self.state, slot.state1,
+                                                  jnp.asarray(i, jnp.int32))
+                        slot.state1 = None
+                        done.append((i, slot, logits[0, -1]))
+            if done:
+                with tel.span("first_token"):
+                    rows = jax.device_get([lg for _, _, lg in done])
+                    for (i, slot, _), row in zip(done, rows):
+                        self._commit(i, slot, self._sample(
+                            slot, row, int(np.argmax(row))), finished)
+            # 3. one batched decode step for every mid-generation slot
+            live = [i for i, s in enumerate(self.slots)
+                    if s is not None and s.state1 is None]
+            if live:
+                with tel.span("decode_inputs"):
+                    toks = np.zeros((self.n_slots, 1), np.int32)
+                    for i in live:
+                        toks[i, 0] = self.slots[i].next_tok
+                    toks = jnp.asarray(toks)
+                with tel.span("decode_step", n_live=len(live)):
+                    with tel.annotate_step("decode_step",
+                                           self.stats.decode_steps):
+                        out = self._decode(self.params, self.plans,
+                                           self.state, toks)
+                    with tel.span("fetch"):
+                        rows, self.state = self._decode_fetch(out, len(live))
+                    self.stats.decode_steps += 1
+                    self.stats.decode_slot_tokens += len(live)
+                    tel.on_decode_step(len(live))
+                    with tel.span("sample"):
+                        greedy = np.argmax(rows, axis=-1)
+                    with tel.span("commit"):
+                        for i in live:
+                            slot = self.slots[i]
+                            self._commit(i, slot, self._sample(
+                                slot, rows[i], int(greedy[i])), finished)
         return finished
 
     def _drain_budget(self) -> int:

@@ -5,6 +5,7 @@ the jit-collected ``SpeculationStats`` totals, and the pJ/token gauge
 agreeing with ``repro.core.energy``."""
 
 import dataclasses
+import itertools
 import json
 import pathlib
 import sys
@@ -297,6 +298,97 @@ def test_paged_pool_metrics_under_pressure():
                    ("engine",)).get(**lab) == st.peak_blocks_in_use
     assert r.gauge("repro_serve_blocks_in_use", "",
                    ("engine",)).get(**lab) == 0    # drained pool
+
+
+PHASES = ("admission", "prefill_chunk", "first_token", "grow_blocks",
+          "decode_inputs", "decode_step")
+DECODE_PHASES = ("fetch", "sample", "commit")
+
+
+@pytest.mark.parametrize("tracing", [False, True], ids=["profiler", "chrome"])
+@pytest.mark.parametrize("engine_cls", [PagedServeEngine,
+                                        ContinuousServeEngine])
+def test_step_phase_spans_reach_the_profiler(monkeypatch, engine_cls,
+                                             tracing):
+    """One step with a live decode and a prefill that finishes: each
+    phase reaches ``jax.profiler.TraceAnnotation`` as ``serve.<phase>``
+    with no args, in order, inside ``serve.step`` and not overlapping
+    another; ``fetch``/``sample``/``commit`` sit inside
+    ``serve.decode_step``; the dispatch ``StepTraceAnnotation``s keep
+    their bare names inside their phase; the decode-step histogram counts
+    the span. NULL_TELEMETRY enters no annotation at all. The contiguous
+    engine grows no blocks."""
+    cfg, params = setup()
+    phases = tuple(p for p in PHASES if engine_cls is PagedServeEngine
+                   or p != "grow_blocks")
+    kw = {"block_size": 4} if engine_cls is PagedServeEngine else {}
+    tick = itertools.count()
+    spans: list = []
+
+    class Annotation:                 # [name, enter tick, exit tick]
+        def __init__(self, name, **args):
+            assert not args, args     # args would suffix the name
+            self.span = [name, None, None]
+
+        def __enter__(self):
+            self.span[1] = next(tick)
+            spans.append(self.span)
+
+        def __exit__(self, *exc):
+            self.span[2] = next(tick)
+
+    class Step(Annotation):
+        def __init__(self, name, step_num):
+            super().__init__(name)
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Annotation)
+    monkeypatch.setattr(jax.profiler, "StepTraceAnnotation", Step)
+    rng = np.random.default_rng(0)
+
+    def two_steps(tel):
+        eng = engine_cls(cfg, params, n_slots=2, max_len=32,
+                         prefill_chunk=4, telemetry=tel, **kw)
+        eng.submit(Request(uid=0, max_new_tokens=6, prompt=rng.integers(
+            0, cfg.vocab_size, 3).astype(np.int32)))
+        eng.step()                    # uid 0 prefilled and decoding
+        eng.submit(Request(uid=1, max_new_tokens=6, prompt=rng.integers(
+            0, cfg.vocab_size, 4).astype(np.int32)))
+        spans.clear()
+        eng.step()                    # uid 1's one chunk, both decode
+        return eng
+
+    two_steps(NULL_TELEMETRY)
+    assert spans == []
+
+    tel = ServeTelemetry(engine="t", tracing=tracing)
+    eng = two_steps(tel)
+    by = {name: (a, b) for name, a, b in spans}
+    assert len(by) == len(spans)      # each span entered once
+    assert set(by) == {f"serve.{p}" for p in ("step",) + phases
+                       + DECODE_PHASES} | {"decode_step", "prefill_chunk"}
+    step = by["serve.step"]
+    assert spans[0][0] == "serve.step"
+    assert all(step[0] < a and b < step[1] for _, a, b in spans[1:])
+
+    def tiled(outer, names):
+        ivs = [by[f"serve.{n}"] for n in names]
+        assert all(outer[0] < a and b < outer[1] for a, b in ivs)
+        assert all(x[1] < y[0] for x, y in zip(ivs, ivs[1:]))
+
+    tiled(step, phases)
+    tiled(by["serve.decode_step"], DECODE_PHASES)
+    for d in ("decode_step", "prefill_chunk"):
+        outer, inner = by[f"serve.{d}"], by[d]
+        assert outer[0] < inner[0] and inner[1] < outer[1]
+    assert by["decode_step"][1] < by["serve.fetch"][0]
+    assert tel.registry.histogram(
+        "repro_serve_decode_step_seconds", "", ("engine",),
+        buckets=STEP_BUCKETS).get(engine="t")["count"] \
+        == eng.stats.decode_steps == 2
+    engine_lane = {e["name"] for e in tel.tracer.events()
+                   if e["ph"] == "X" and e["tid"] == 0}
+    assert engine_lane == ({"step", *phases, *DECODE_PHASES} if tracing
+                           else set())
 
 
 # ----------------------------------------------------------- pim depth
