@@ -11,7 +11,8 @@ One chip, one process, three phases:
     be unset, and every kernel op must resolve to ``pallas-tpu``. Each
     kernel is then run at the model's widths (the lm_head's d_model x
     vocab included) and must be bit-exact, counters included, against
-    ``repro/kernels/ref.py`` (the ``xla`` backend).
+    ``repro/kernels/ref.py`` (the ``xla`` backend); paged decode
+    attention, whose f32 sums run in another order, within one bf16 ulp.
 (b) ``fast`` serve: ``repro.launch.serve``'s own steps drive the paged
     engine at full width on the Pallas kernels and again on ``xla``. The
     prefill and first-decode logits must agree within ``LOGIT_RTOL``, and
@@ -127,6 +128,32 @@ def phase_kernels(cfg, kernel: str, batch: int = 8) -> None:
     print(f"(a) fused_spec_crossbar {batch}x{d}x{f} spec slicing "
           f"{speculation.SPEC_SLICING}: psum, spec failures {fl.tolist()} "
           f"and {int(rs)} recovery saturations bit-exact vs ref")
+
+    # paged decode attention: 4 of `batch` slots live, in scrambled blocks
+    H, K, hd, bs = cfg.n_heads, cfg.n_kv_heads, cfg.resolved_head_dim, 16
+    lens = np.array([700, 1, 16, 2048] + [1] * (batch - 4))
+    n_blocks, max_blocks = 320, 128
+    tables = np.full((batch, max_blocks), n_blocks, np.int32)
+    order, used = rng.permutation(n_blocks), 0
+    for b, n in enumerate(-(-lens[:4] // bs)):
+        tables[b, :n] = order[used:used + n]
+        used += n
+    kq, kk, kv = jax.random.split(key, 3)
+    pool = (n_blocks + 1, bs, K, hd)
+    args = (jax.random.normal(kq, (batch, H, hd), "bfloat16"),
+            jax.random.normal(kk, pool, "bfloat16"),
+            jax.random.normal(kv, pool, "bfloat16"), tables, lens)
+    got, want = (np.asarray(ops.paged_decode_attention(
+        *args, sentinel=n_blocks, backend=be), np.float32)
+        for be in (kernel, "xla"))
+    err = np.abs(got[:4] - want[:4]).max()
+    # both round an f32 result to bf16 once: one bf16 ulp apart at most
+    check(bool(np.allclose(got[:4], want[:4], rtol=2 ** -7, atol=1e-5))
+          and not got[4:].any(),
+          f"paged_decode_attention: {kernel} vs ref max |diff| {err}")
+    print(f"(a) paged_decode_attention {batch} slots x {H} heads, lengths "
+          f"{lens[:4].tolist()}: max |diff| vs ref {err:.3g} (within one "
+          f"bf16 ulp), all-sentinel slots exact zeros")
 
 
 # ---------------------------------------------------------- serving

@@ -35,6 +35,7 @@ from repro.dist.sharding import _active_mesh
 from repro.kernels import fused_crossbar as _fx
 from repro.kernels import fused_spec_crossbar as _fs
 from repro.kernels import int8_matmul as _im
+from repro.kernels import paged_attention as _pa
 from repro.kernels import ref as _ref
 from repro.kernels import sliced_crossbar as _sx
 
@@ -91,6 +92,20 @@ def centered_int8_matmul(x_q: jnp.ndarray, w_off: jnp.ndarray,
 
     ``backend`` follows the registry selection order."""
     return dispatch("centered_int8_matmul", backend)(x_q, w_off, centers)
+
+
+def paged_decode_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
+                           v_pool: jnp.ndarray, tables: jnp.ndarray,
+                           kv_len: jnp.ndarray, *, sentinel: int,
+                           backend: str | None = None) -> jnp.ndarray:
+    """One query token per slot over its paged KV blocks.
+
+    q (B, H, D); k_pool, v_pool (pool_rows, block_size, K, D); tables
+    (B, max_blocks) int32 pool rows, ``sentinel`` where unmapped; kv_len
+    (B,) int32. Returns (B, H, D) in q's dtype. ``backend`` follows the
+    registry selection order."""
+    return dispatch("paged_decode_attention", backend)(
+        q, k_pool, v_pool, tables, kv_len, sentinel=sentinel)
 
 
 def sliced_crossbar_matmul(x_slices: jnp.ndarray, w_planes: jnp.ndarray,
@@ -283,3 +298,9 @@ register("fused_spec_crossbar", "interpret",
          _pallas(_fs.fused_spec_crossbar, interpret=True))
 register("fused_spec_crossbar", "pallas-tpu",
          _pallas(_fs.fused_spec_crossbar, interpret=False))
+
+register("paged_decode_attention", "xla", _ref.paged_decode_attention)
+register("paged_decode_attention", "interpret",
+         _pallas(_pa.paged_decode_attention, interpret=True))
+register("paged_decode_attention", "pallas-tpu",
+         _pallas(_pa.paged_decode_attention, interpret=False))

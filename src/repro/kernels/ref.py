@@ -146,3 +146,51 @@ def fused_spec_crossbar(x_u8: jnp.ndarray, w_planes: jnp.ndarray,
             out = out + value.sum(axis=1) * mults[i, j]
         fails.append(fail_i)
     return out, jnp.stack(fails), rsats
+
+
+def decode_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
+                     kv_len: jnp.ndarray | int) -> jnp.ndarray:
+    """One query per slot against a contiguous cache, softmax in f32.
+
+    q: (B, 1, H, D); k, v: (B, Sk, K, D) with H = K * G; kv_len: int or
+    (B,) valid entries. No chunk scan: it keeps the KV cache's sequence
+    sharding intact (a scan would slice the sharded seq dim per step,
+    forcing GSPMD to all-gather the whole cache every chunk); the softmax
+    over the sharded seq dim lowers to partial max/sum + a tiny (B,H)
+    all-reduce — flash-decode semantics.
+    """
+    B, _, H, D = q.shape
+    _, Sk, K, _ = k.shape
+    G = H // K
+    kv_len_ = jnp.asarray(kv_len, jnp.int32)
+    if kv_len_.ndim == 0:
+        kv_len_ = jnp.broadcast_to(kv_len_[None], (B,))
+    qg1 = q.reshape(B, K, G, D).astype(jnp.float32)
+    s = jnp.einsum("bkgd,bckd->bkgc", qg1,
+                   k.astype(jnp.float32)) * (D ** -0.5)
+    kpos = jnp.arange(Sk)
+    mask = kpos[None, :] < kv_len_[:, None]          # (B, Sk)
+    s = jnp.where(mask[:, None, None, :], s, -jnp.inf)
+    p = jax.nn.softmax(s, axis=-1)
+    out = jnp.einsum("bkgc,bckd->bkgd", p, v.astype(jnp.float32))
+    return out.reshape(B, 1, H, D).astype(q.dtype)
+
+
+def paged_decode_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
+                           v_pool: jnp.ndarray, tables: jnp.ndarray,
+                           kv_len: jnp.ndarray, *,
+                           sentinel: int) -> jnp.ndarray:
+    """Pure-XLA reference for ``paged_attention.paged_decode_attention``.
+
+    Gathers each slot's whole ``max_blocks * block_size`` view through
+    its table (sentinel entries read the scratch block, at or past
+    ``kv_len``, so the mask zeroes them exactly) and attends over it:
+    bit-identical to ``decode_attention`` over a contiguous cache of the
+    same length. q (B, H, D) -> (B, H, D).
+    """
+    B, max_blocks = tables.shape
+    view_shape = (B, max_blocks * k_pool.shape[1]) + k_pool.shape[2:]
+    rows = jnp.clip(tables, 0, sentinel)
+    k = k_pool[rows].reshape(view_shape)
+    v = v_pool[rows].reshape(view_shape)
+    return decode_attention(q[:, None], k, v, kv_len)[:, 0]

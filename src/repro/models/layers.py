@@ -25,6 +25,7 @@ from repro.core import backends as device_backends
 from repro.core import center_offset as co
 from repro.core import pim_linear
 from repro.dist import shard
+from repro.kernels import ref as kref
 from repro.quant import quantize as quantlib
 
 ATTN_CHUNK = 512
@@ -337,23 +338,8 @@ def chunked_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
     _, Sk, K, _ = k.shape
     G = H // K
     if Sq == 1:
-        # decode fast path: one query — no chunk scan. Keeps the KV cache's
-        # sequence sharding intact (a scan would slice the sharded seq dim
-        # per step, forcing GSPMD to all-gather the whole cache every
-        # chunk); the softmax over the sharded seq dim lowers to partial
-        # max/sum + a tiny (B,H) all-reduce — flash-decode semantics.
-        kv_len_ = jnp.asarray(kv_len, jnp.int32)
-        if kv_len_.ndim == 0:
-            kv_len_ = jnp.broadcast_to(kv_len_[None], (B,))
-        qg1 = q.reshape(B, K, G, D).astype(jnp.float32)
-        s = jnp.einsum("bkgd,bckd->bkgc", qg1,
-                       k.astype(jnp.float32)) * (D ** -0.5)
-        kpos = jnp.arange(Sk)
-        mask = kpos[None, :] < kv_len_[:, None]          # (B, Sk)
-        s = jnp.where(mask[:, None, None, :], s, -jnp.inf)
-        p = jax.nn.softmax(s, axis=-1)
-        out = jnp.einsum("bkgc,bckd->bkgd", p, v.astype(jnp.float32))
-        return out.reshape(B, 1, H, D).astype(q.dtype)
+        # decode fast path: one query, no chunk scan
+        return kref.decode_attention(q, k, v, kv_len)
     nk = -(-Sk // chunk)
     pad = nk * chunk - Sk
     kp = jnp.pad(k, ((0, 0), (0, pad), (0, 0), (0, 0)))

@@ -33,6 +33,8 @@ import jax.numpy as jnp
 
 from repro.configs.base import ArchConfig
 from repro.dist import shard
+from repro.kernels import ops as kops
+from repro.kernels.paged_attention import pool_view
 from repro.models import layers as L
 from repro.models import ssm as S
 
@@ -366,10 +368,18 @@ def _paged_write_token(pool: jnp.ndarray, new: jnp.ndarray,
     Slots whose table entry is the sentinel (idle, retired, or still
     mid-prefill — the scheduler hands ``decode_step`` a sentinel row for
     them) write into the scratch block, which no live gather ever
-    unmasks."""
+    unmasks. A K/V leaf is written through the lane-dense view of its
+    blocks that the paged attention kernel reads, so on a TPU the layer's
+    pool stays in one layout between the write and the read."""
     B = new.shape[0]
     bi = jnp.clip(pos // paged.block_size, 0, tables.shape[1] - 1)
     rows = tables[jnp.arange(B), bi]
+    if pool.ndim == 4:
+        c, lanes = pool_view(*pool.shape[2:])
+        view = pool.reshape(pool.shape[:2] + (pool.shape[2] // c, lanes))
+        tok = new[:, 0].reshape((B,) + view.shape[2:])
+        return view.at[rows, pos % paged.block_size].set(
+            tok.astype(pool.dtype)).reshape(pool.shape)
     return pool.at[rows, pos % paged.block_size].set(
         new[:, 0].astype(pool.dtype))
 
@@ -410,8 +420,10 @@ def _attn_decode(bp: dict, cfg: ArchConfig, cache: dict, x: jnp.ndarray,
     ``pos`` is a scalar (lockstep: the whole batch shares one position) or
     a ``(B,)`` vector (continuous batching: one position per slot). With
     ``tables``/``paged`` the cache leaves are block pools: the new token
-    scatters into each slot's current block and attention reads a
-    block-table gather of the slot's history.
+    scatters into each slot's current block, and attention reads the
+    slot's live blocks in place (``kernels.ops.paged_decode_attention``)
+    or, for an int8 pool, dequantizes a block-table gather of the slot's
+    history.
     """
     B = x.shape[0]
     pos = jnp.asarray(pos, jnp.int32)
@@ -455,10 +467,13 @@ def _attn_decode(bp: dict, cfg: ArchConfig, cache: dict, x: jnp.ndarray,
     else:
         new_cache = {"k": write(cache["k"], k_new),
                      "v": write(cache["v"], v_new)}
-        k_cache = gather(new_cache["k"])
-        v_cache = gather(new_cache["v"])
-    out = L.chunked_attention(q, k_cache, v_cache, q_positions=positions,
-                              kv_len=pos + 1, causal=True)
+        k_cache, v_cache = new_cache["k"], new_cache["v"]
+    if tables is not None and not int8_cache:
+        out = kops.paged_decode_attention(q[:, 0], k_cache, v_cache, tables,
+                                          pos + 1, sentinel=paged.sentinel)
+    else:
+        out = L.chunked_attention(q, k_cache, v_cache, q_positions=positions,
+                                  kv_len=pos + 1, causal=True)
     y = L.pim_matmul(out.reshape(B, 1, -1), bp["core"]["wo"],
                      L.plan_leaf(plans, "wo"), cfg)
     return new_cache, y

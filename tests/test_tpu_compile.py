@@ -91,6 +91,24 @@ def test_fused_spec_crossbar_compiles(one_chip, no_compile_cache):
     _assert_kernel(fn.lower(*_crossbar_args(one_chip)).compile())
 
 
+# (slots, heads, KV heads, head_dim, pool blocks, blocks per slot) of the
+# chip cells: qwen1.5-0.5b chat and yi-6b batch
+PAGED = {"qwen05_chat": (32, 16, 16, 64, 2048, 160),
+         "yi6b_batch": (64, 32, 4, 128, 11264, 256)}
+
+
+@pytest.mark.parametrize("cell", sorted(PAGED))
+def test_paged_decode_attention_compiles(one_chip, no_compile_cache, cell):
+    B, H, K, D, n_blocks, max_blocks = PAGED[cell]
+    pool = _spec((n_blocks + 1, 16, K, D), jnp.bfloat16, one_chip)
+    fn = jax.jit(lambda q, k, v, t, n: ops.paged_decode_attention(
+        q, k, v, t, n, sentinel=n_blocks, backend="pallas-tpu"))
+    compiled = fn.lower(_spec((B, H, D), jnp.bfloat16, one_chip), pool, pool,
+                        _spec((B, max_blocks), jnp.int32, one_chip),
+                        _spec((B,), jnp.int32, one_chip)).compile()
+    _assert_kernel(compiled)
+
+
 def test_kernel_compiles_under_two_chip_mesh(topo, no_compile_cache):
     """GSPMD cannot partition a Mosaic kernel; under a mesh the registry
     runs it whole on each device, so a sharded operand still compiles."""
