@@ -9,7 +9,11 @@ configuration, traffic mix, correctness limit and metrics are files under
 this directory, found by name:
 
 - ``configs/<config>.json``: the model, as its public ``config.json``
-  states it (``weights.py`` maps it onto the program's config);
+  states it, and under ``family`` the kind of model it is;
+- ``families/<family>.py``: what the harness knows about that kind of
+  model: the file's keys mapped onto the program's config, the weights,
+  the plain reference and its controls, and the work counts behind the
+  rooflines and the MFU (``families/__init__.py`` gives the interface);
 - ``traffic/<traffic>.json``: arrivals, lengths and the serving settings
   (``traffic.py`` is the one generator);
 - ``limits/<workload>.json``: the numbers ``correct`` compares, each

@@ -4,8 +4,8 @@
         --seeds 1,2,3 --seconds 20
 
 For each seed, in one process: a full run of the cell (set-up, window,
-the plain reference over the sample), and on the same sample each int4
-control of ``reference.CONTROLS`` put in the program's place. One JSON
+the plain reference over the sample), and on the same sample each
+control of its family's ``CONTROLS`` put in the program's place. One JSON
 line per seed: for the served tokens (``program``) and for each control,
 every number the cell's limits file names, beside its limit, and the
 ``correct`` those numbers give. A limit lies between the largest program

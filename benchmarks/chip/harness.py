@@ -2,6 +2,11 @@
 serve the traffic through the window, read the metrics, check the
 served tokens against the plain reference.
 
+Everything that depends on the kind of model (its weights, its plain
+reference and controls, its work counts) comes from the family its
+configuration names, ``families/<family>.py``; this module calls only
+the interface ``families/__init__.py`` describes.
+
 ``bench.py`` is the command; this module is what it and the tests drive.
 """
 
@@ -19,7 +24,6 @@ import time
 
 import stats
 import traffic as traffic_gen
-import weights as wmod
 
 HERE = pathlib.Path(__file__).resolve().parent
 ROOT = HERE.parents[1]
@@ -42,6 +46,7 @@ class Cell:
     root: pathlib.Path          # checkout root (holds src/ and BENCHMARK.json)
     bench_dir: pathlib.Path     # where its configs, traffic, limits, metrics are
     model: dict                 # configs/<config>.json
+    family: object              # families/<model["family"]>.py, loaded
     traffic: dict               # traffic/<traffic>.json
     limits: dict                # limits/<workload>.json
     end_to_end: list[dict]
@@ -78,9 +83,20 @@ def resolve(root: pathlib.Path, workload: str,
                if not check_file(k, bench_dir).is_file()]
     if missing:
         raise CellError(f"{workload}: missing {missing}")
+    model = json.loads(files["config"].read_text())
+    if "family" not in model:
+        raise CellError(f"{files['config']}: names no family")
+    path = family_file(model["family"], bench_dir)
+    if not path.is_file():
+        raise CellError(f"{workload}: missing {path}")
+    family = load_family(path)
+    try:
+        family.check(model)
+    except ValueError as e:
+        raise CellError(f"{files['config']}: {e}")
     return Cell(
         name=workload, chips=int(w["chips"]), root=root, bench_dir=bench_dir,
-        model=wmod.load_config(w["config"], bench_dir),
+        model=model, family=family,
         traffic=json.loads(files["traffic"].read_text()),
         limits=limits,
         end_to_end=[m for m in spec["end_to_end"] if _for_cell(m, workload)],
@@ -100,12 +116,32 @@ def check_file(name: str, bench_dir: pathlib.Path = HERE) -> pathlib.Path:
     return bench_dir / "checks" / f"{name}.py"
 
 
-def _reader(path: pathlib.Path):
+def family_file(name: str, bench_dir: pathlib.Path = HERE) -> pathlib.Path:
+    return bench_dir / "families" / f"{name}.py"
+
+
+def _module(path: pathlib.Path):
     spec = importlib.util.spec_from_file_location(
         f"bench_{path.parent.name}_{path.stem.replace('.', '_')}", path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    return mod.read
+    return mod
+
+
+def _reader(path: pathlib.Path):
+    return _module(path).read
+
+
+_FAMILIES: dict = {}
+
+
+def load_family(path: pathlib.Path):
+    """The family module at ``path``, loaded once per process (its jitted
+    functions keep their compiled programs across cells)."""
+    path = path.resolve()
+    if path not in _FAMILIES:
+        _FAMILIES[path] = _module(path)
+    return _FAMILIES[path]
 
 
 def read_metric(name: str, run, bench_dir: pathlib.Path = HERE):
@@ -132,6 +168,7 @@ class Run:
     """What a metric reader reads. Times are ``time.perf_counter()``
     seconds; ``t0``/``t_end`` bound the measured window."""
     model: dict
+    family: object              # the cell's family module
     peak: dict | None
     setup_s: float
     plan_compile_s: float
@@ -166,15 +203,22 @@ class _Compiles:
             self.n["compile"] += 1
 
 
-def _arch_config(model: dict, serving: dict):
+def _arch_config(cell: Cell):
+    """The program's config: the registry entry the file's ``arch`` names,
+    with the family's overrides and the traffic's PIM mode. Every
+    attribute the family names has to read back as it gave it."""
     from repro import configs
-    base = configs.get(model["arch"])
-    over = {field: model[key] for key, field in wmod.ARCH_FIELDS.items()}
-    arch = dataclasses.replace(base, **over, head_dim=0,
-                               pim_mode=serving["pim"],
-                               pim_kernel_backend="auto")
-    if arch.resolved_head_dim != wmod.head_dim(model):
-        raise CellError(f"{model['arch']}: head size differs from the file")
+    base = configs.get(cell.model["arch"])
+    over = cell.family.arch_overrides(cell.model)
+    fields = {f.name for f in dataclasses.fields(base)}
+    arch = dataclasses.replace(
+        base, **{k: v for k, v in over.items() if k in fields},
+        pim_mode=cell.traffic["serving"]["pim"], pim_kernel_backend="auto")
+    differ = {k: (v, getattr(arch, k, None)) for k, v in over.items()
+              if getattr(arch, k, None) != v}
+    if differ:
+        raise CellError(f"{cell.model['arch']}: the program's config differs "
+                        f"from the file (file, program): {differ}")
     return arch
 
 
@@ -223,10 +267,10 @@ def build(cell: Cell, seed: int, seconds: float, trace: bool) -> Session:
     import bench_telemetry
 
     serving, model = cell.traffic["serving"], cell.model
-    arch = _arch_config(model, serving)
+    arch = _arch_config(cell)
     vocab = model["vocab_size"]
     rng = np.random.default_rng([seed, 1])
-    params = jax.block_until_ready(wmod.make_weights(model, seed))
+    params = jax.block_until_ready(cell.family.make_weights(model, seed))
     calib = rng.integers(0, vocab, CALIB_SHAPE, dtype=np.int32)
     t = time.perf_counter()
     compiled = pim.compile_pim_params(params, arch, calib)
@@ -359,10 +403,10 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
              control: bool = False) -> dict:
     """One run of ``cell``. ``hook(engine)``, where given, is called once
     the engine is warm (the tests break the timed path through it). With
-    ``control`` the result also holds, under ``control``, each int4
-    control of ``reference.CONTROLS`` judged on the same sample by the
-    same limits as the served tokens: its numbers and its ``correct``;
-    benchmark runs never run the controls."""
+    ``control`` the result also holds, under ``control``, each control of
+    the family's ``CONTROLS`` judged on the same sample by the same limits
+    as the served tokens: its numbers and its ``correct``; benchmark runs
+    never run the controls."""
     sess = build(cell, seed, seconds, trace)
     import jax
     if hook is not None:
@@ -383,7 +427,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
     dev = jax.devices()[0]
     mem = (dev.memory_stats() or {}).get("peak_bytes_in_use")
     tel = sess.tel
-    run = Run(model=cell.model, peak=peak,
+    run = Run(model=cell.model, family=cell.family, peak=peak,
               setup_s=setup_s, plan_compile_s=sess.plan_compile_s,
               t0=win.t0, t_end=win.t_end,
               due=win.due if not backlog else {},
@@ -456,13 +500,13 @@ def check_served(cell: Cell, params, prompts: dict, finished: dict,
     longest among them), by which a served token's logit lies below the
     plain reference's best; each number the cell's limits file names is
     read from the gaps by its reader, ``checks/<name>.py``, and held to
-    its limit. With ``control``, each control of ``reference.CONTROLS``
-    is put in the program's place on the same sample and held to the
-    same limits: ``{control: {"checks": ..., "correct": ...}}``."""
+    its limit (the gaps come from the family's ``served_gaps``). With
+    ``control``, each control of the family's ``CONTROLS`` is put in the
+    program's place on the same sample and held to the same limits:
+    ``{control: {"checks": ..., "correct": ...}}``."""
     import numpy as np
 
-    import reference
-    lim = cell.limits
+    family, lim = cell.family, cell.limits
     readers = {k: _reader(check_file(k, cell.bench_dir))
                for k in lim["limits"]}
     rng = np.random.default_rng([seed, 2])
@@ -482,9 +526,9 @@ def check_served(cell: Cell, params, prompts: dict, finished: dict,
     def judge(precision):
         got = dict.fromkeys(readers)
         if seqs:
-            g = reference.served_gaps(cell.model, params, seqs,
-                                      cell.traffic["serving"]["max_len"],
-                                      precision)
+            g = family.served_gaps(cell.model, params, seqs,
+                                   cell.traffic["serving"]["max_len"],
+                                   precision)
             got = {k: f(g) for k, f in readers.items()}
         return {k: {"value": (float(v) if v is not None and math.isfinite(v)
                               else None),
@@ -497,7 +541,7 @@ def check_served(cell: Cell, params, prompts: dict, finished: dict,
     ctl = None
     if control:
         ctl = {"program": {"checks": checks, "correct": verdict(checks)}}
-        for p in reference.CONTROLS:
+        for p in family.CONTROLS:
             c = judge(p)
             ctl[p] = {"checks": c, "correct": verdict(c)}
     return checks, ctl

@@ -233,8 +233,9 @@ def int8_matmul_roofline_pct(trace: Trace, peak: dict) -> float | None:
 
 
 def step_mfu_pct(run, peak: dict) -> float | None:
-    """Model operations of the tokens made in the traced window, each
-    class over its own peak, as a share of the window."""
+    """Model operations of the tokens made in the traced window (the
+    family's ``model_ops``), each class over its own peak, as a share of
+    the window."""
     tr = run.trace
     if tr is None or tr.host_span is None:
         return None
@@ -244,6 +245,6 @@ def step_mfu_pct(run, peak: dict) -> float | None:
     decode = [run.prompt_len[u] + k - 1
               for u, times in run.token_times.items()
               for k, t in enumerate(times) if k > 0 and a <= t < b]
-    i8, bf = roofline.model_ops(run.model, prefill, decode)
+    i8, bf = run.family.model_ops(run.model, prefill, decode)
     need = i8 / peak["int8_ops_per_s"] + bf / peak["bf16_flops_per_s"]
     return 100.0 * need / (b - a)

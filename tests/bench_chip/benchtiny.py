@@ -17,6 +17,7 @@ if str(BENCH) not in sys.path:
 
 import harness  # noqa: E402
 
+DENSE = harness.load_family(harness.family_file("dense", BENCH))
 TINY = dict(hidden_size=128, intermediate_size=256, num_hidden_layers=2,
             num_attention_heads=4, num_key_value_heads=4, vocab_size=4096)
 # The widest logit gap at this size (CPU, seeds 1-3): 0.04-0.16 for the
@@ -42,13 +43,15 @@ BACKLOG = dict(TRAFFIC, arrivals={"kind": "backlog", "requests": 12},
 def make_root(tmp: pathlib.Path) -> pathlib.Path:
     """A checkout-like root: the real ``src``, a ``BENCHMARK.json`` with
     two cells, ``tiny_chat`` (open loop) and ``tiny_batch`` (backlog), and
-    their files beside the real metric readers."""
+    their files beside the real metric readers, checks and families."""
     bench = tmp / "benchmarks" / "chip"
     (bench / "configs").mkdir(parents=True)
     (bench / "traffic").mkdir()
     (bench / "limits").mkdir()
     shutil.copytree(BENCH / "metrics", bench / "metrics")
     shutil.copytree(BENCH / "checks", bench / "checks")
+    shutil.copytree(BENCH / "families", bench / "families",
+                    ignore=shutil.ignore_patterns("__pycache__"))
     (tmp / "src").symlink_to(REPO / "src")
     cfg = json.loads((BENCH / "configs" / "qwen1.5-0.5b.json").read_text())
     cfg.update(TINY)
@@ -77,9 +80,9 @@ def make_root(tmp: pathlib.Path) -> pathlib.Path:
 
 def run(root: pathlib.Path, seed: int = 5, seconds: float = 2.0,
         trace: bool = False, hook=None, control: bool = False,
-        cell: str = "tiny_chat") -> dict:
+        cell: str = "tiny_chat", peak: dict | None = None) -> dict:
     """One run of a tiny cell on the CPU (the look for a chip skipped)."""
     cell = harness.resolve(root, cell, root / "benchmarks" / "chip")
-    return harness.run_cell(cell, seed, seconds, trace, peak=None,
+    return harness.run_cell(cell, seed, seconds, trace, peak=peak,
                             t_process=time.perf_counter(), hook=hook,
                             control=control)

@@ -33,7 +33,8 @@ def _run(kernel_ns):
     trace = tr.Trace(ops=ops, modules=[], host=[("bench.window", 0, 100_000)],
                      window=(0, 100_000), host_span=(10.0, 11.0))
     return types.SimpleNamespace(
-        model=YI, peak=V5E, trace=trace, prompt_len={7: 1000, 8: 20},
+        model=YI, family=benchtiny.DENSE, peak=V5E, trace=trace,
+        prompt_len={7: 1000, 8: 20},
         token_times={7: [9.9, 10.2, 10.4, 11.2], 8: [10.1, 10.3]})
 
 
@@ -55,7 +56,7 @@ def test_bytes_of_known_decode_positions():
     # request 7's tokens 1 and 2 (positions 1000, 1001) and request 8's
     # token 1 (position 20) land in the span; token 0 is the prefill's
     assert sorted(m.decode_positions(run)) == [20, 1000, 1001]
-    ops, nbytes = m.work(YI, [20, 1000, 1001])
+    ops, nbytes = benchtiny.DENSE.decode_attention_work(YI, [20, 1000, 1001])
     keys = 21 + 1001 + 1002
     # per layer: K and V of every key (4 heads x 128), then each token's
     # query and output (32 heads x 128), all bf16
@@ -67,8 +68,7 @@ def test_bytes_of_known_decode_positions():
 @pytest.mark.parametrize("name", ["paged_attn_roofline.chat",
                                   "paged_attn_roofline.batch"])
 def test_kernel_at_its_least_time_reads_100(name):
-    m = _reader_module()
-    _, nbytes = m.work(YI, [20, 1000, 1001])
+    _, nbytes = benchtiny.DENSE.decode_attention_work(YI, [20, 1000, 1001])
     least_ns = nbytes / V5E["hbm_bytes_per_s"] * 1e9
     assert harness.metric_file(name).name == "paged_attn_roofline.py"
     share = _read(name, _run([least_ns / 2, least_ns / 2]))

@@ -46,6 +46,12 @@ def test_configs_are_their_files():
         assert c["file"] == f"benchmarks/chip/configs/{c['name']}.json"
 
 
+def test_each_config_names_a_family_file():
+    for c in SPEC["configs"]:
+        cfg = json.loads((benchtiny.REPO / c["file"]).read_text())
+        assert harness.family_file(cfg["family"]).is_file()
+
+
 def test_names_units_and_bounds():
     metrics = SPEC["end_to_end"] + SPEC["per_layer"]
     names = [m["name"] for m in metrics]

@@ -11,6 +11,8 @@ import benchtiny
 import roofline
 import trace_reduce as tr
 
+DENSE = benchtiny.DENSE
+
 V5E = {"bf16_flops_per_s": 197e12, "int8_ops_per_s": 393e12,
        "hbm_bytes_per_s": 819e9, "hbm_bytes": 16e9}
 KERNEL = ("%centered_int8_matmul.79 = s32[32,2816]{1,0:T(8,128)S(1)} "
@@ -81,13 +83,13 @@ def test_model_ops_by_hand():
            "num_hidden_layers": 24, "num_attention_heads": 16,
            "num_key_value_heads": 16, "vocab_size": 151936}
     per_layer = 4 * 1024 * 1024 + 3 * 1024 * 2816
-    assert roofline.proj_ops_per_token(cfg) == 2 * per_layer * 24
+    assert DENSE.proj_ops_per_token(cfg) == 2 * per_layer * 24
     # one decode token at position 99 attends 100 keys in each layer
-    i8, bf = roofline.model_ops(cfg, [], [99])
+    i8, bf = DENSE.model_ops(cfg, [], [99])
     assert i8 == 2 * per_layer * 24 + 2 * 1024 * 151936
     assert bf == 4 * 16 * 64 * 100 * 24
     # a prompt of 3 tokens in one chunk: 1 + 2 + 3 keys, the head once
-    i8, bf = roofline.model_ops(cfg, [(0, 3, True)], [])
+    i8, bf = DENSE.model_ops(cfg, [(0, 3, True)], [])
     assert i8 == 3 * 2 * per_layer * 24 + 2 * 1024 * 151936
     assert bf == 4 * 16 * 64 * 6 * 24
 
@@ -99,10 +101,10 @@ def test_step_mfu_counts_the_tokens_of_the_traced_window():
     trace = _synthetic()
     trace.host_span = (10.0, 11.0)
     run = types.SimpleNamespace(
-        model=cfg, trace=trace, prompt_len={7: 100},
+        model=cfg, family=DENSE, trace=trace, prompt_len={7: 100},
         prefill_chunks=[(10.5, 7, 0, 100), (9.0, 7, 0, 100)],
         token_times={7: [10.6, 10.7, 11.5]})
-    i8, bf = roofline.model_ops(cfg, [(0, 100, True)], [100])
+    i8, bf = DENSE.model_ops(cfg, [(0, 100, True)], [100])
     want = 100 * (i8 / 393e12 + bf / 197e12) / 1.0
     assert tr.step_mfu_pct(run, V5E) == pytest.approx(want)
 
